@@ -20,6 +20,8 @@ def main() -> None:
     from benchmarks import (bench_ablation, bench_coact, bench_kernels,
                             bench_latency, bench_pcie, bench_roofline,
                             bench_skew, bench_tables)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sections = [
         ("Table 1 (latency scenarios)", bench_latency),
         ("Fig. 6 (activation skew)", bench_skew),

@@ -51,9 +51,14 @@ def _kernel(s_ref, gate_ref, m_ref, b_ref, q_ref, out_ref, sub_ref, miss_ref,
     new_s = s
     sub = jnp.zeros((t_n, k_n), jnp.int32)
     miss = jnp.zeros((t_n, k_n), jnp.int32)
+    # columns are read and written through an iota mask: Mosaic refuses the
+    # zero-width slices a concatenate-based column update makes at k == 0
+    col = jax.lax.broadcasted_iota(jnp.int32, (t_n, k_n), 1)
 
     for k in range(k_n):
-        e = new_s[:, k]                                       # [T]
+        at_k = col == k                                       # [T, K]
+        e = jnp.sum(jnp.where(at_k, new_s, 0).astype(jnp.float32),
+                    axis=1).astype(jnp.int32)                 # [T] column k
         res_e = _onehot_select(e, m) > 0.5                    # [T]
         need = (~res_e) & (gate > 0) & (budget > 0)           # [T]
 
@@ -65,9 +70,8 @@ def _kernel(s_ref, gate_ref, m_ref, b_ref, q_ref, out_ref, sub_ref, miss_ref,
             valid = b_r >= 0
             b_safe = jnp.maximum(b_r, 0)
             res_b = _onehot_select(b_safe, m) > 0.5
-            in_row = jnp.zeros((t_n,), bool)
-            for kk in range(k_n):
-                in_row = in_row | (new_s[:, kk] == b_safe)
+            in_row = jnp.sum((new_s == b_safe[:, None]).astype(jnp.float32),
+                             axis=1) > 0.5
             elig = valid & res_b & (~in_row)
             psi = q_r - r * 1e-7                              # rank tie-break
             better = elig & (psi > best_psi)
@@ -76,13 +80,10 @@ def _kernel(s_ref, gate_ref, m_ref, b_ref, q_ref, out_ref, sub_ref, miss_ref,
 
         do_sub = need & (best_b >= 0)
         new_col = jnp.where(do_sub, best_b, e)
-        new_s = jnp.concatenate(
-            [new_s[:, :k], new_col[:, None], new_s[:, k + 1:]], axis=1)
-        sub = jnp.concatenate(
-            [sub[:, :k], do_sub.astype(jnp.int32)[:, None], sub[:, k + 1:]], axis=1)
-        miss_col = ((~res_e) & (~do_sub)).astype(jnp.int32)
-        miss = jnp.concatenate(
-            [miss[:, :k], miss_col[:, None], miss[:, k + 1:]], axis=1)
+        miss_col = (~res_e) & (~do_sub)
+        new_s = jnp.where(at_k, new_col[:, None], new_s)
+        sub = jnp.where(at_k, do_sub.astype(jnp.int32)[:, None], sub)
+        miss = jnp.where(at_k, miss_col.astype(jnp.int32)[:, None], miss)
         budget = budget - do_sub.astype(jnp.int32)
 
     out_ref[...] = new_s

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python/XLA-CPU for correctness validation; on TPU they compile
-via Mosaic. ``interpret`` is chosen automatically from the backend.
+On TPU the kernels compile via Mosaic. On CPU (the test suite) they execute
+in interpret mode — the kernel body runs in Python/XLA-CPU for correctness
+validation. Any other backend raises: interpret mode there would hide that
+the kernels are not running on an accelerator.
 """
 from __future__ import annotations
 
@@ -17,7 +18,11 @@ from repro.kernels.wkv_chunk import wkv_chunk_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels run on tpu (Mosaic) or cpu "
+                           f"(interpret mode), not on {backend!r}")
+    return backend == "cpu"
 
 
 def buddy_substitute(s, gate, resident, table, q, *, h: int = 8, rho: int = 3):

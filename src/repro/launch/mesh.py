@@ -9,17 +9,19 @@ jax device state — smoke tests must keep seeing 1 CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_devices: int = 8):
     """Small mesh over however many (fake) devices tests set up."""
-    return jax.make_mesh((n_devices // 2, 2), ("data", "model"))
+    return jax.make_mesh((n_devices // 2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes(multi_pod: bool):

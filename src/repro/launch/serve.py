@@ -55,6 +55,7 @@ import numpy as np
 
 from repro.configs.base import get_config, get_reduced
 from repro.core import BuddyPolicy, CoactivationRecorder, build_buddy_lists
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer
 from repro.runtime.cache import ExpertCache
 from repro.runtime.placement import PlacementController
@@ -96,7 +97,8 @@ def profile_buddies(cfg, params, lm, *, steps: int = 4, batch: int = 4,
     return build_buddy_lists(q, alpha=alpha, k_max=k_max, activity=rec.A), rec
 
 
-def main():
+def parse_args(argv=None):
+    """The launcher's command line (``argv`` None: ``sys.argv``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-v2-lite-buddy")
     ap.add_argument("--reduced", action="store_true")
@@ -254,7 +256,7 @@ def main():
                          "expected stall saved (P(use) x miss cost) is at "
                          "or below this many seconds (<0: auto = 1%% of a "
                          "full expert transfer)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.lookahead < 1:
         ap.error("--lookahead must be >= 1 (layers ahead to prefetch)")
     if args.prefill_chunk < 1:
@@ -266,8 +268,23 @@ def main():
     if args.prefix_cache and not args.paged_kv:
         ap.error("--prefix-cache shares KV at block granularity: it "
                  "requires --paged-kv")
+    return args
 
+
+def main():
+    args = parse_args()
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
+    serve(cfg, args)
+
+
+def serve(cfg, args):
+    """Build the engine for ``cfg`` (random weights from seed 0, or
+    ``--checkpoint``), profile buddies, and serve as ``args`` say.
+
+    Returns (engine, summary, outputs): the scheduler's summary in
+    continuous mode and the engine's in batch mode; ``outputs`` holds the
+    generated token ids of each request (continuous) or batch row."""
     assert cfg.is_moe, "serving engine targets MoE archs"
     key = jax.random.PRNGKey(0)
     params = transformer.init_params(cfg, key)
@@ -331,8 +348,8 @@ def main():
                       prefix_cache=args.prefix_cache, placement=placement)
 
     if args.mode == "continuous":
-        _serve_continuous(args, cfg, eng, lm, prefetch_k)
-        return
+        s, outputs = _serve_continuous(args, cfg, eng, lm, prefetch_k)
+        return eng, s, outputs
 
     prompts = lm.sample(args.batch, 8)
     out = eng.generate(prompts, max_new_tokens=args.steps)
@@ -352,6 +369,7 @@ def main():
     _report_placement(s)
     print("sample output tokens:", out[0, -16:].tolist())
     _report_telemetry(eng.telemetry, args.trace_out)
+    return eng, s, list(out[:, prompts.shape[1]:])
 
 
 def _report_mesh(s):
@@ -410,7 +428,8 @@ def _report_telemetry(tele, trace_out):
 
 
 def _serve_continuous(args, cfg, eng, lm, prefetch_k):
-    """Drive the engine with continuously arriving requests + SLOs."""
+    """Drive the engine with continuously arriving requests + SLOs.
+    Returns (summary, generated token ids of each completed request)."""
     slo = SLOConfig(
         ttft_s=args.slo_ttft_ms * 1e-3 if args.slo_ttft_ms > 0 else None,
         tpot_s=args.slo_tpot_ms * 1e-3 if args.slo_tpot_ms > 0 else None,
@@ -462,6 +481,7 @@ def _serve_continuous(args, cfg, eng, lm, prefetch_k):
     _report_prefix(s.get("engine", {}))
     _report_placement(s.get("engine", {}))
     _report_telemetry(eng.telemetry, args.trace_out)
+    return s, [np.asarray(r.tokens) for r in sched.completed]
 
 
 def _report_prefix(s):

@@ -52,13 +52,21 @@ def shard(x: jax.Array, *names: Optional[str]) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Initializers
 # ---------------------------------------------------------------------------
+def scaled_normal(key, shape, scale: float) -> jax.Array:
+    """``jax.random.normal(key, shape) * scale`` with the same bits under jit
+    as eagerly: the samples pass an optimization barrier, so XLA cannot fuse
+    the scaling into the sampler's arithmetic (which then rounds
+    differently)."""
+    return jax.lax.optimization_barrier(jax.random.normal(key, shape)) * scale
+
+
 def dense_init(key, in_dim: int, out_dim: int, dtype) -> jax.Array:
     scale = (2.0 / (in_dim + out_dim)) ** 0.5
-    return (jax.random.normal(key, (in_dim, out_dim)) * scale).astype(dtype)
+    return scaled_normal(key, (in_dim, out_dim), scale).astype(dtype)
 
 
 def embed_init(key, vocab: int, dim: int, dtype) -> jax.Array:
-    return (jax.random.normal(key, (vocab, dim)) * 0.02).astype(dtype)
+    return scaled_normal(key, (vocab, dim), 0.02).astype(dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SSMConfig
-from repro.models.common import dense_init, shard
+from repro.models.common import dense_init, scaled_normal, shard
 
 
 def init_mamba(key, d_model: int, cfg: SSMConfig, dtype) -> dict:
@@ -20,7 +20,7 @@ def init_mamba(key, d_model: int, cfg: SSMConfig, dtype) -> dict:
     ks = jax.random.split(key, 6)
     return {
         "in_proj": dense_init(ks[0], d_model, 2 * inner + 2 * cfg.state_dim + n_heads, dtype),
-        "conv_w": (jax.random.normal(ks[1], (cfg.conv_dim, inner)) * 0.1).astype(dtype),
+        "conv_w": scaled_normal(ks[1], (cfg.conv_dim, inner), 0.1).astype(dtype),
         "A_log": jnp.zeros((n_heads,), jnp.float32),
         "D": jnp.ones((n_heads,), jnp.float32),
         "dt_bias": jnp.zeros((n_heads,), jnp.float32),

@@ -18,7 +18,7 @@ from repro.configs.base import MoEConfig
 from repro.core.policy import BuddyPolicy
 from repro.core.substitute import SubstituteResult, substitute
 from repro.kernels.ref import dequant_swiglu
-from repro.models.common import dense_init, shard, swiglu
+from repro.models.common import dense_init, scaled_normal, shard, swiglu
 
 
 class BuddyState(NamedTuple):
@@ -60,9 +60,9 @@ def init_moe(key, d_model: int, cfg: MoEConfig, dtype) -> dict:
         def up(k, shape_in, shape_out, transpose=False):
             base = dense_init(jax.random.fold_in(k, 0), shape_in, shape_out,
                               jnp.float32)
-            noise = jax.random.normal(jax.random.fold_in(k, 1),
-                                      (e, shape_in, shape_out)) \
-                * n * (2.0 / (shape_in + shape_out)) ** 0.5
+            noise = scaled_normal(jax.random.fold_in(k, 1),
+                                  (e, shape_in, shape_out), n) \
+                * (2.0 / (shape_in + shape_out)) ** 0.5
             return (base[None] + noise).astype(dtype)
 
         p = {

@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import dense_init, rmsnorm, shard
+from repro.models.common import dense_init, rmsnorm, scaled_normal, shard
 
 
 def init_rwkv(key, d_model: int, num_heads: int, head_dim: int, d_ff: int,
@@ -28,7 +28,7 @@ def init_rwkv(key, d_model: int, num_heads: int, head_dim: int, d_ff: int,
         "wg": dense_init(ks[4], d_model, dh, dtype),
         "ww": dense_init(ks[5], d_model, dh, dtype),
         "w_bias": jnp.zeros((dh,), jnp.float32),
-        "u": (jax.random.normal(ks[6], (num_heads, head_dim)) * 0.1).astype(jnp.float32),
+        "u": scaled_normal(ks[6], (num_heads, head_dim), 0.1).astype(jnp.float32),
         "wo": dense_init(ks[7], dh, d_model, dtype),
         # channel mix
         "mu_c": (jax.random.uniform(ks[8], (2, d_model)) * 0.5 + 0.25).astype(jnp.float32),

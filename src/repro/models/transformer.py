@@ -86,7 +86,13 @@ def _stack(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key) -> dict:
+    """Seeded random parameters, each group's blocks stacked on a leading
+    axis. Jitted, and each group built stacked under vmap, so that only the
+    stacked arrays are ever materialised: no per-layer copy sits beside
+    them. On the CPU the values equal a per-layer eager build's bit for bit,
+    save the last bit of some upcycled expert weights (``upcycle_noise``)."""
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 8)
     params: dict = {"embed": embed_init(keys[0], cfg.vocab_size, cfg.d_model, dtype)}
@@ -95,9 +101,9 @@ def init_params(cfg: ModelConfig, key) -> dict:
     groups = []
     for gi, (kind, repeat) in enumerate(cfg.stack()):
         gkey = jax.random.fold_in(keys[2], gi)
-        blocks = [_init_block(jax.random.fold_in(gkey, i), kind, cfg, dtype)
-                  for i in range(repeat)]
-        groups.append(_stack(blocks))
+        groups.append(jax.vmap(
+            lambda i: _init_block(jax.random.fold_in(gkey, i), kind, cfg,
+                                  dtype))(jnp.arange(repeat)))
     params["groups"] = tuple(groups)
     if cfg.family == "hybrid":
         # zamba2 shared attention block — ONE param set reused at every
@@ -124,6 +130,7 @@ class StepCtx(NamedTuple):
     remat: bool = False            # checkpoint each scanned block (training)
     tok_valid: Any = None          # [B, C] prefix validity mask (chunk mode)
     block_tables: Any = None       # [B, MB] paged-KV block table (None = ring)
+    dropless: bool = False         # full mode: no expert capacity limit
 
 
 def _attn_kwargs(cfg: ModelConfig):
@@ -199,7 +206,7 @@ def block_forward(kind: str, p, x, cache, ctx: StepCtx, buddy=None,
                 p["moe"], xn, cfg.moe, policy=ctx.policy, buddy=buddy,
                 jitter_key=ctx.rng,
                 capacity_factor=2.0 if ctx.mode == "step" else 1.25,
-                dropless=ctx.mode == "chunk")
+                dropless=ctx.dropless or ctx.mode == "chunk")
             aux = _moe_aux_dict(cfg, moe_aux, ctx.record)
         else:
             y = swiglu(xn, p["ffn"]["w1"], p["ffn"]["w3"], p["ffn"]["w2"])
@@ -474,15 +481,19 @@ def _iter_groups(params, cfg, caches, buddies):
 def forward_train(params, cfg: ModelConfig, tokens, *, cond_embeds=None,
                   policy: Optional[BuddyPolicy] = None, buddies=None,
                   rng=None, record: bool = False, window: int = -1,
-                  remat: bool = False):
-    """Full-sequence forward. Returns (logits [B, S_tok, V], aux)."""
+                  remat: bool = False, dropless: bool = False):
+    """Full-sequence forward. Returns (logits [B, S_tok, V], aux).
+
+    ``dropless``: give every expert room for every routed slot, as decode
+    has; without it a crowded expert drops the slots past its capacity."""
     if window < 0:
         window = cfg.sliding_window
     b, s = tokens.shape
     x = _embed(params, cfg, tokens, cond_embeds)
     positions = jnp.broadcast_to(jnp.arange(x.shape[1]), (b, x.shape[1]))
     cross = _project_cross(params, cfg, cond_embeds)
-    ctx = StepCtx(cfg, "full", window, policy, positions, rng, record, remat)
+    ctx = StepCtx(cfg, "full", window, policy, positions, rng, record, remat,
+                  dropless=dropless)
 
     total_aux = _zero_moe_aux(cfg)
     rec = []

@@ -2,9 +2,10 @@
 
 The paper's regime: experts offloaded to host memory, fetched over PCIe
 (~10 ms / expert on Mixtral-8x7B; transfers are 85-94% of latency on edge
-deployments, §2.4). The container is CPU-only, so transfer latency and device
-compute are MODELED (constants below, documented for the TPU v5e target);
-bytes and event counts are exact. Accuracy effects of substitution are real.
+deployments, §2.4). The engine's clock is a MODEL built from the constants
+below (documented for the TPU v5e target), not a measurement, on any
+backend: a time it yields is a simulated time. Bytes and event counts are
+exact, and accuracy effects of substitution are real.
 """
 from __future__ import annotations
 

@@ -10,6 +10,10 @@ except ImportError:
     import _hypothesis_stub
     _hypothesis_stub.install()
 
+# The suite runs on the CPU, also on a machine with a chip: the chip stays
+# free for one process, and the dry-run subprocess inherits the setting.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 import jax  # noqa: E402
 
 # CPU tests must see exactly 1 device (the dry-run subprocess sets its own

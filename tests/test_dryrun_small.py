@@ -27,7 +27,8 @@ from repro.training.train_loop import make_train_step
 
 arch = "ARCH"
 cfg = dataclasses.replace(get_reduced(arch), dtype="bfloat16")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 prof = ShardingProfile()
 rules = activation_rules(prof, cfg, 2)
 
