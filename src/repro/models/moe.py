@@ -9,6 +9,7 @@ each data shard — no all-to-all on the baseline path.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -235,6 +236,43 @@ def _fused_dispatch(params: dict, x_flat, new_idx, degraded, skip,
     return y_rep.astype(x_flat.dtype), n_cap_dropped.astype(jnp.int32)
 
 
+def dispatch_plan(policy: Optional[BuddyPolicy], tokens_shape, num_experts: int,
+                  top_k: int, *, dropless: bool = False,
+                  capacity_factor: float = 1.25) -> tuple:
+    """Which dispatch ``moe_forward`` runs for tokens of ``tokens_shape``
+    ([B, S] or [T]) and each expert's capacity: ("fused", cap),
+    ("gather", 0) or ("capacity", cap). Static: shapes and flags only."""
+    t_n = math.prod(tokens_shape)
+    one_token = len(tokens_shape) == 2 and tokens_shape[1] == 1
+    if policy is not None and policy.use_fused_dispatch:
+        if dropless or one_token:
+            return "fused", t_n * top_k           # decode / chunked prefill
+        cap = int(max(top_k, t_n * top_k / num_experts * capacity_factor))
+        return "fused", min(t_n * top_k, -(-cap // 8) * 8)
+    if not dropless and one_token and t_n * top_k < num_experts:
+        return "gather", 0
+    rows = tokens_shape[0] if len(tokens_shape) == 2 else 1
+    s_k = t_n // rows * top_k
+    if dropless:
+        return "capacity", s_k
+    cap = int(max(top_k, s_k / num_experts * capacity_factor))
+    return "capacity", min(s_k, -(-cap // 8) * 8)
+
+
+def dispatch_slots(plan: tuple, tokens_shape, num_experts: int, top_k: int,
+                   use_kernel: bool = False) -> int:
+    """Expert-FFN slots one layer's dispatch computes under ``plan``: every
+    expert's capacity on every row on the capacity path, every (expert,
+    class) group's capacity in the grouped kernel, else one per slot."""
+    path, cap = plan
+    if path == "capacity":
+        rows = tokens_shape[0] if len(tokens_shape) == 2 else 1
+        return rows * num_experts * cap
+    if path == "fused" and use_kernel:
+        return 2 * num_experts * cap
+    return math.prod(tokens_shape) * top_k
+
+
 def moe_forward(params: dict, x: jax.Array, cfg: MoEConfig, *,
                 policy: Optional[BuddyPolicy] = None,
                 buddy: Optional[BuddyState] = None,
@@ -254,7 +292,11 @@ def moe_forward(params: dict, x: jax.Array, cfg: MoEConfig, *,
     missed slot computes against the resident low-precision replica in the
     SAME fused step (mixed-precision dispatch) — zero transfer, bounded
     fidelity loss. With quant_tier='off' this path is compiled out entirely
-    and the graph is bit-identical to the pre-tier engine."""
+    and the graph is bit-identical to the pre-tier engine.
+
+    Named scopes: ``router``, ``substitute``, ``dispatch`` (scatter or
+    gather, the expert FFN and the combine, on every path) and
+    ``shared_experts``."""
     orig_shape = x.shape
     d = x.shape[-1]
     x_flat = x.reshape(-1, d)
@@ -266,18 +308,85 @@ def moe_forward(params: dict, x: jax.Array, cfg: MoEConfig, *,
     quant_ok = buddy.quant_ok if (use_tier and buddy is not None) else None
     tier_fid_cost = (buddy.fid_cost
                      if (use_tier and buddy is not None) else None)
+    run_degraded = use_tier and (quant_ok is not None
+                                 or tier_fid_cost is not None)
+    path, cap = dispatch_plan(policy, x.shape[:-1], e_n, k_n,
+                              dropless=dropless,
+                              capacity_factor=capacity_factor)
 
-    logits, idx, topk_logits, probs = router_topk(
-        params["router"], x_flat, k_n, jitter_key, cfg.router_jitter)
+    with jax.named_scope("router"):
+        logits, idx, topk_logits, probs = router_topk(
+            params["router"], x_flat, k_n, jitter_key, cfg.router_jitter)
 
-    # ---------------- BuddyMoE substitution (Alg. 1) ----------------
+    with jax.named_scope("substitute"):
+        new_idx, substituted, missed, degraded, dropped, peered = \
+            _substitute(idx, topk_logits, logits, buddy, policy, quant_ok,
+                        tier_fid_cost)
+        weights = probs
+        if policy is not None and policy.fallback == "drop":
+            # missed slots are skipped; renormalize over the surviving set
+            weights = jnp.where(missed, 0.0, weights)
+            weights = weights / jnp.maximum(weights.sum(-1, keepdims=True),
+                                            1e-9)
+        if policy is not None and policy.miss_policy == "cost":
+            # slots the cost argmin chose to drop: skip + renormalize
+            # (per-slot counterpart of the global fallback='drop' above)
+            weights = jnp.where(dropped, 0.0, weights)
+            weights = weights / jnp.maximum(weights.sum(-1, keepdims=True),
+                                            1e-9)
+
+    with jax.named_scope("dispatch"):
+        if path == "fused":
+            # slots whose mixture weight was zeroed above — never computed
+            skip = dropped
+            if policy.fallback == "drop":
+                skip = skip | missed
+            y_rep, n_dropped = _fused_dispatch(
+                params, x_flat, new_idx, degraded, skip,
+                run_degraded, use_kernel, cap)
+            yk = y_rep.reshape(t_n, k_n, d)
+        elif path == "gather":
+            yk = _gather_dispatch(params, x_flat, new_idx, degraded,
+                                  run_degraded).reshape(t_n, k_n, d)
+            n_dropped = jnp.zeros((), jnp.int32)
+        else:
+            yk, n_dropped = _capacity_dispatch(
+                params, x, x_flat, new_idx, degraded, run_degraded,
+                use_kernel, cap)
+        y = (yk * weights[..., None].astype(x.dtype)).sum(1)
+
+    if cfg.num_shared_experts and "shared" in params:
+        with jax.named_scope("shared_experts"):
+            y = y + swiglu(x_flat, params["shared"]["w1"],
+                           params["shared"]["w3"], params["shared"]["w2"])
+
+    # ---------------- load-balance loss (Switch-style) ----------------
+    p_mean = jax.nn.softmax(logits, axis=-1).mean(0)               # [E]
+    onehot = jax.nn.one_hot(new_idx.reshape(-1), e_n, dtype=jnp.float32)
+    f_frac = onehot.reshape(t_n, k_n, e_n).sum(1).mean(0)          # [E]
+    lb = e_n * jnp.sum(f_frac * p_mean)
+
+    miss_per_expert = jnp.zeros((e_n,), jnp.int32).at[idx.reshape(-1)].add(
+        missed.reshape(-1).astype(jnp.int32))
+
+    aux = MoEAux(lb, new_idx, idx, probs,
+                 substituted.sum(), missed.sum(), n_dropped, miss_per_expert,
+                 substituted, missed, degraded.sum(), degraded,
+                 dropped.sum(), dropped, peered.sum(), peered)
+    return y.reshape(orig_shape), aux
+
+
+def _substitute(idx, topk_logits, logits, buddy, policy, quant_ok,
+                tier_fid_cost):
+    """Algorithm 1's outcome for every slot. Returns (new_idx, substituted,
+    missed, degraded, dropped, peered)."""
     if policy is not None and buddy is not None:
         # substitute() owns the four-way miss split for EVERY mode,
         # including mode='none' (no rerouting, but misses still route to
         # the degraded tier before the fetch/drop fallback). In cost mode
         # the per-expert cost vectors replace the quant_ok precedence mask.
         # tier_fid_cost (not raw buddy.fid_cost): the degraded COMPUTE path
-        # below is gated on use_tier, so the argmin's degraded option must
+        # is gated on use_tier, so the argmin's degraded option must
         # be too — a finite fid_cost without quant params would mark slots
         # degraded and then silently compute them at full precision
         res: SubstituteResult = substitute(
@@ -285,131 +394,64 @@ def moe_forward(params: dict, x: jax.Array, cfg: MoEConfig, *,
             router_logits=logits, hop=buddy.hop, quant_ok=quant_ok,
             fid_cost=tier_fid_cost, fetch_cost=buddy.fetch_cost,
             peer_ok=buddy.peer_ok, peer_cost=buddy.peer_cost)
-        new_idx, substituted, missed = res.indices, res.substituted, res.missed
-        degraded = res.degraded
-        dropped = (res.dropped if res.dropped is not None
-                   else jnp.zeros_like(missed))
-        peered = (res.peered if res.peered is not None
-                  else jnp.zeros_like(missed))
-    elif buddy is not None:         # no policy: raw residency miss count
+        missed = res.missed
+        zero = jnp.zeros_like(missed)
+        return (res.indices, res.substituted, missed, res.degraded,
+                res.dropped if res.dropped is not None else zero,
+                res.peered if res.peered is not None else zero)
+    if buddy is not None:           # no policy: raw residency miss count
         missed = ~buddy.resident[idx]
-        new_idx = idx
-        substituted = jnp.zeros_like(missed)
-        degraded = jnp.zeros_like(missed)
-        dropped = jnp.zeros_like(missed)
-        peered = jnp.zeros_like(missed)
-    else:
-        new_idx = idx
-        substituted = jnp.zeros(idx.shape, bool)
-        missed = jnp.zeros(idx.shape, bool)
-        degraded = jnp.zeros(idx.shape, bool)
-        dropped = jnp.zeros(idx.shape, bool)
-        peered = jnp.zeros(idx.shape, bool)
-    run_degraded = use_tier and (quant_ok is not None
-                                 or tier_fid_cost is not None)
+        zero = jnp.zeros_like(missed)
+        return idx, zero, missed, zero, zero, zero
+    zero = jnp.zeros(idx.shape, bool)
+    return idx, zero, zero, zero, zero, zero
 
-    weights = probs
-    if policy is not None and policy.fallback == "drop":
-        # missed slots are skipped; renormalize over the surviving set
-        weights = jnp.where(missed, 0.0, weights)
-        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
-    if policy is not None and policy.miss_policy == "cost":
-        # slots the cost argmin chose to drop: skip + renormalize (per-slot
-        # counterpart of the global fallback='drop' above)
-        weights = jnp.where(dropped, 0.0, weights)
-        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
 
-    # ---------------- single-dispatch fused hot path ----------------------
-    # One compute step for the whole four-way miss outcome: full-precision
-    # and buddy slots read the fp table at the RESOLVED id, degraded slots
-    # read the quant replica at the TRUE id, dropped slots (zero mixture
-    # weight) are skipped entirely. Replaces the three-dispatch split below
-    # (expert_ffn path + buddy-replica einsum + separate degraded pass).
-    if policy is not None and policy.use_fused_dispatch:
-        # slots whose mixture weight was zeroed above — never computed
-        skip = dropped
-        if policy.fallback == "drop":
-            skip = skip | missed
-        if dropless or (x.ndim == 3 and x.shape[1] == 1):
-            cap = t_n * k_n                       # decode / chunked prefill
-        else:
-            cap = int(max(k_n, t_n * k_n / e_n * capacity_factor))
-            cap = min(t_n * k_n, -(-cap // 8) * 8)
-        y_rep, n_dropped = _fused_dispatch(
-            params, x_flat, new_idx, degraded, skip,
-            run_degraded, use_kernel, cap)
-        y = (y_rep.reshape(t_n, k_n, d)
-             * weights[..., None].astype(x.dtype)).sum(1)
-        if cfg.num_shared_experts and "shared" in params:
-            y = y + swiglu(x_flat, params["shared"]["w1"],
-                           params["shared"]["w3"], params["shared"]["w2"])
-        p_mean = jax.nn.softmax(logits, axis=-1).mean(0)
-        onehot_f = jax.nn.one_hot(new_idx.reshape(-1), e_n,
-                                  dtype=jnp.float32)
-        f_frac = onehot_f.reshape(t_n, k_n, e_n).sum(1).mean(0)
-        lb = e_n * jnp.sum(f_frac * p_mean)
-        miss_per_expert = jnp.zeros((e_n,), jnp.int32) \
-            .at[idx.reshape(-1)].add(missed.reshape(-1).astype(jnp.int32))
-        aux = MoEAux(lb, new_idx, idx, probs, substituted.sum(),
-                     missed.sum(), n_dropped, miss_per_expert,
-                     substituted, missed, degraded.sum(), degraded,
-                     dropped.sum(), dropped, peered.sum(), peered)
-        return y.reshape(orig_shape), aux
+def _gather_dispatch(params, x_flat, new_idx, degraded, run_degraded):
+    """Active-expert gather (tiny-batch decode): per-slot outputs [T*K, D].
 
-    # ---------------- active-expert gather (tiny-batch decode) -----------
-    # When the whole batch selects fewer expert-slots than there are experts
-    # (long-context decode, B*K < E), gathering the selected experts' weight
-    # rows reads only the ACTIVE experts from HBM — the dense dispatch path
-    # below streams all E experts' weights every step. §Perf iteration 6.
-    if not dropless and x.ndim == 3 and x.shape[1] == 1 and t_n * k_n < e_n:
-        e_flat = new_idx.reshape(-1)                               # [T*K]
-        w1s = params["w1"][e_flat]                                 # [T*K, D, F]
-        w3s = params["w3"][e_flat]
-        w2s = params["w2"][e_flat]
-        xr = jnp.repeat(x_flat, k_n, axis=0)                       # [T*K, D]
-        h = jax.nn.silu(jnp.einsum("td,tdf->tf", xr, w1s,
-                                   preferred_element_type=jnp.float32))
-        g = jnp.einsum("td,tdf->tf", xr, w3s,
-                       preferred_element_type=jnp.float32)
-        hg = (h * g).astype(x.dtype)
-        hg = shard(hg, None, "dff")
-        y_rep = jnp.einsum("tf,tfd->td", hg, w2s,
-                           preferred_element_type=jnp.float32).astype(x.dtype)
-        if run_degraded:
-            y_deg = _degraded_outputs(params["quant"], x_flat, e_flat)
-            y_rep = jnp.where(degraded.reshape(-1)[:, None],
-                              y_deg.astype(x.dtype), y_rep)
-        y = (y_rep.reshape(t_n, k_n, d)
-             * weights[..., None].astype(x.dtype)).sum(1)
-        if cfg.num_shared_experts and "shared" in params:
-            y = y + swiglu(x_flat, params["shared"]["w1"],
-                           params["shared"]["w3"], params["shared"]["w2"])
-        p_mean = jax.nn.softmax(logits, axis=-1).mean(0)
-        onehot_f = jax.nn.one_hot(e_flat, e_n, dtype=jnp.float32)
-        f_frac = onehot_f.reshape(t_n, k_n, e_n).sum(1).mean(0)
-        lb = e_n * jnp.sum(f_frac * p_mean)
-        miss_per_expert = jnp.zeros((e_n,), jnp.int32).at[idx.reshape(-1)].add(
-            missed.reshape(-1).astype(jnp.int32))
-        aux = MoEAux(lb, new_idx, idx, probs, substituted.sum(), missed.sum(),
-                     jnp.zeros((), jnp.int32), miss_per_expert,
-                     substituted, missed, degraded.sum(), degraded,
-                     dropped.sum(), dropped, peered.sum(), peered)
-        return y.reshape(orig_shape), aux
+    When the whole batch selects fewer expert-slots than there are experts
+    (long-context decode, B*K < E), gathering the selected experts' weight
+    rows reads only the ACTIVE experts from HBM — the dense dispatch path
+    streams all E experts' weights every step. §Perf iteration 6."""
+    k_n = new_idx.shape[1]
+    e_flat = new_idx.reshape(-1)                                   # [T*K]
+    w1s = params["w1"][e_flat]                                     # [T*K, D, F]
+    w3s = params["w3"][e_flat]
+    w2s = params["w2"][e_flat]
+    xr = jnp.repeat(x_flat, k_n, axis=0)                           # [T*K, D]
+    h = jax.nn.silu(jnp.einsum("td,tdf->tf", xr, w1s,
+                               preferred_element_type=jnp.float32))
+    g = jnp.einsum("td,tdf->tf", xr, w3s,
+                   preferred_element_type=jnp.float32)
+    hg = (h * g).astype(x_flat.dtype)
+    hg = shard(hg, None, "dff")
+    y_rep = jnp.einsum("tf,tfd->td", hg, w2s,
+                       preferred_element_type=jnp.float32).astype(x_flat.dtype)
+    if run_degraded:
+        y_deg = _degraded_outputs(params["quant"], x_flat, e_flat)
+        y_rep = jnp.where(degraded.reshape(-1)[:, None],
+                          y_deg.astype(x_flat.dtype), y_rep)
+    return y_rep
 
-    # ---------------- capacity-based dispatch (row-local) ----------------
-    # Dispatch independently per batch row so that with the batch sharded
-    # over `data` the scatter/gather and expert compute are collective-free
-    # (tokens never cross data shards; experts are TP-sharded on d_ff).
+
+def _capacity_dispatch(params, x, x_flat, new_idx, degraded, run_degraded,
+                       use_kernel: bool, cap: int):
+    """Capacity-based dispatch (row-local): per-slot outputs [T, K, D] and
+    the count of slots past an expert's capacity.
+
+    Dispatch independently per batch row so that with the batch sharded
+    over `data` the scatter/gather and expert compute are collective-free
+    (tokens never cross data shards; experts are TP-sharded on d_ff)."""
+    d = x.shape[-1]
+    t_n = x_flat.shape[0]
+    e_n = params["w1"].shape[0]
+    k_n = new_idx.shape[1]
     rows = x.shape[0] if x.ndim == 3 else 1
     s_n = t_n // rows
     row_e = new_idx.reshape(rows, s_n * k_n)                        # [B, S*K]
     onehot = jax.nn.one_hot(row_e, e_n, dtype=jnp.float32)          # [B, S*K, E]
     pos = (jnp.cumsum(onehot, axis=1) * onehot).sum(-1).astype(jnp.int32) - 1
-    if dropless:
-        cap = s_n * k_n
-    else:
-        cap = int(max(k_n, s_n * k_n / e_n * capacity_factor))
-        cap = min(s_n * k_n, -(-cap // 8) * 8)
     kept = pos < cap
     n_dropped = (~kept).sum()
     pos_safe = jnp.where(kept, pos, cap)                            # cap -> dropped
@@ -448,22 +490,4 @@ def moe_forward(params: dict, x: jax.Array, cfg: MoEConfig, *,
                                   new_idx.reshape(-1))
         yk = jnp.where(degraded[..., None],
                        y_deg.reshape(t_n, k_n, d).astype(x.dtype), yk)
-    y = (yk * weights[..., None].astype(x.dtype)).sum(1)
-
-    if cfg.num_shared_experts and "shared" in params:
-        y = y + swiglu(x_flat, params["shared"]["w1"], params["shared"]["w3"],
-                       params["shared"]["w2"])
-
-    # ---------------- load-balance loss (Switch-style) ----------------
-    p_mean = jax.nn.softmax(logits, axis=-1).mean(0)               # [E]
-    f_frac = onehot.reshape(t_n, k_n, e_n).sum(1).mean(0)          # [E]
-    lb = e_n * jnp.sum(f_frac * p_mean)
-
-    miss_per_expert = jnp.zeros((e_n,), jnp.int32).at[idx.reshape(-1)].add(
-        missed.reshape(-1).astype(jnp.int32))
-
-    aux = MoEAux(lb, new_idx, idx, probs,
-                 substituted.sum(), missed.sum(), n_dropped, miss_per_expert,
-                 substituted, missed, degraded.sum(), degraded,
-                 dropped.sum(), dropped, peered.sum(), peered)
-    return y.reshape(orig_shape), aux
+    return yk, n_dropped

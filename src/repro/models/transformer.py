@@ -12,6 +12,11 @@ Three entry points (shared layer code):
 
 Serving state for MoE archs: ``buddies`` is a BuddyState with leading layer
 axis [L_moe, ...]; ``policy`` (static) selects Original / Random / BuddyMoE.
+
+Named scopes (``jax.named_scope``, the ops' ``op_name`` metadata): ``embed``,
+``attention`` (with its KV write), the MoE layer's ``router``,
+``substitute``, ``dispatch`` and ``shared_experts`` (models/moe.py), and
+``lm_head``.
 """
 from __future__ import annotations
 
@@ -160,6 +165,25 @@ def _self_attn(p, x, cache, ctx: StepCtx):
     return y, cache
 
 
+def _moe_dispatch_args(mode: str, dropless: bool = False) -> dict:
+    """``moe_forward``'s capacity factor and dropless flag in each mode."""
+    return {"capacity_factor": 2.0 if mode == "step" else 1.25,
+            "dropless": dropless or mode == "chunk"}
+
+
+def moe_slots_computed(cfg: ModelConfig, policy: Optional[BuddyPolicy],
+                       mode: str, tokens_shape) -> int:
+    """Expert-FFN slots one serving step's dispatch computes, summed over
+    the MoE layers: ``tokens_shape`` is [B, 1] for a decode step ("step")
+    and [B, C] for a prefill chunk ("chunk"); the path is the one
+    ``moe_forward`` takes for it."""
+    e, k = cfg.moe.num_experts, cfg.moe.top_k
+    plan = moe_mod.dispatch_plan(policy, tokens_shape, e, k,
+                                 **_moe_dispatch_args(mode))
+    n_moe = sum(r for kind, r in cfg.stack() if kind == ATTN_MOE)
+    return n_moe * moe_mod.dispatch_slots(plan, tokens_shape, e, k)
+
+
 def _zero_moe_aux(cfg: ModelConfig):
     e = cfg.moe.num_experts if cfg.is_moe else 1
     return {"lb": jnp.zeros((), jnp.float32),
@@ -197,16 +221,17 @@ def block_forward(kind: str, p, x, cache, ctx: StepCtx, buddy=None,
     cfg = ctx.cfg
     aux = None
     if kind in (ATTN_DENSE, ATTN_MOE):
-        h, cache_kv = _self_attn(p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps),
-                                 cache["kv"] if cache else None, ctx)
+        with jax.named_scope("attention"):
+            h, cache_kv = _self_attn(p["attn"],
+                                     rmsnorm(x, p["ln1"], cfg.norm_eps),
+                                     cache["kv"] if cache else None, ctx)
         x = x + h
         xn = rmsnorm(x, p["ln2"], cfg.norm_eps)
         if kind == ATTN_MOE:
             y, moe_aux = moe_mod.moe_forward(
                 p["moe"], xn, cfg.moe, policy=ctx.policy, buddy=buddy,
-                jitter_key=ctx.rng,
-                capacity_factor=2.0 if ctx.mode == "step" else 1.25,
-                dropless=ctx.dropless or ctx.mode == "chunk")
+                jitter_key=ctx.rng, **_moe_dispatch_args(ctx.mode,
+                                                         ctx.dropless))
             aux = _moe_aux_dict(cfg, moe_aux, ctx.record)
         else:
             y = swiglu(xn, p["ffn"]["w1"], p["ffn"]["w3"], p["ffn"]["w2"])
@@ -263,9 +288,10 @@ def _run_group(kind: str, gparams, x, gcache, ctx: StepCtx, gbuddy=None,
                 x, nc, _ = block_forward(MAMBA2, blk, x, mc, ctx)
                 mcaches.append(nc if has_cache else None)
             # shared attention application
-            h, kv = _self_attn(shared_attn["attn"],
-                               rmsnorm(x, lp["ln_attn"], cfg.norm_eps),
-                               lc["kv"] if has_cache else None, ctx)
+            with jax.named_scope("attention"):
+                h, kv = _self_attn(shared_attn["attn"],
+                                   rmsnorm(x, lp["ln_attn"], cfg.norm_eps),
+                                   lc["kv"] if has_cache else None, ctx)
             x = x + h
             xn = rmsnorm(x, shared_attn["ln2"], cfg.norm_eps)
             x = x + swiglu(xn, shared_attn["ffn"]["w1"], shared_attn["ffn"]["w3"],
@@ -440,8 +466,9 @@ def _stack_n(fn, n):
 # Entry points
 # ===========================================================================
 def _embed(params, cfg: ModelConfig, tokens, cond_embeds):
-    x = params["embed"][tokens]
-    x = shard(x, "batch", None, None)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        x = shard(x, "batch", None, None)
     if cfg.num_cond_tokens and cfg.family == "audio":
         # audio: stubbed codec frame embeddings as a causal prefix
         pre = (cond_embeds @ params["cond_proj"]).astype(x.dtype)
@@ -456,11 +483,13 @@ def _project_cross(params, cfg, cond_embeds):
 
 
 def _logits(params, cfg: ModelConfig, x):
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("...d,dv->...v", x, head,
-                        preferred_element_type=jnp.float32)
-    return shard(logits, "batch", *([None] * (logits.ndim - 2)), "vocab")
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("...d,dv->...v", x, head,
+                            preferred_element_type=jnp.float32)
+        return shard(logits, "batch", *([None] * (logits.ndim - 2)),
+                     "vocab")
 
 
 def _iter_groups(params, cfg, caches, buddies):
@@ -526,7 +555,8 @@ def decode_step(params, cfg: ModelConfig, token, caches, pos, *,
     (logits [B, V], new_caches, aux)."""
     if window < 0:
         window = cfg.sliding_window
-    x = params["embed"][token][:, None, :]            # [B, 1, D]
+    with jax.named_scope("embed"):
+        x = params["embed"][token][:, None, :]        # [B, 1, D]
     if cfg.family == "audio" and cfg.num_cond_tokens:
         pos = pos + cfg.num_cond_tokens
     ctx = StepCtx(cfg, "step", window, policy, pos, rng, record,
@@ -576,8 +606,9 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, caches, base_pos,
         f"SSM/hybrid/VLM states are sequential), got {cfg.stack()}"
     if window < 0:
         window = cfg.sliding_window
-    x = params["embed"][tokens]                       # [B, C, D]
-    x = shard(x, "batch", None, None)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]                   # [B, C, D]
+        x = shard(x, "batch", None, None)
     base_pos = jnp.asarray(base_pos, jnp.int32)
     if cfg.family == "audio" and cfg.num_cond_tokens:
         base_pos = base_pos + cfg.num_cond_tokens

@@ -39,11 +39,25 @@ Zero-overhead-when-off contract: the recorder is opt-in. Call sites hold
 ``None`` instead of a recorder and guard every emission with an ``is not
 None`` check, so a telemetry-off run executes the exact pre-telemetry code
 path (bit-identical outputs and summaries).
+
+Host spans on the profiler's clock (``host_span``): the serving loop's own
+host work — each engine step and its parts, sampling, and the scheduler's
+admission, batching, emission and feedback — is also named on the wall
+clock, as ``jax.profiler.TraceAnnotation`` events. They land in the same
+``.xplane.pb`` as the device's ops, so a profiler trace shows which host
+work the device waits on. Their names (``SERVE_SPANS``) and the counters
+attached to ``serve.account`` (``STEP_COUNTERS``) are fixed here and
+documented in docs/trace_schema.md. With no profiler trace running a span
+records nothing and costs one check.
 """
 from __future__ import annotations
 
+import collections
 import json
+import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Canonical track names -> Chrome trace pids (stable, documented in
 # docs/trace_schema.md). Unknown tracks get pids past the known ones.
@@ -55,6 +69,68 @@ REQUEST_KINDS = ("arrive", "queued", "prefill", "decode", "token",
 LAYER_KINDS = ("compute", "stall", "outcomes")
 TRANSFER_KINDS = ("transfer", "start", "escalate")
 ENGINE_KINDS = ("step", "budget", "prefix_hit")
+
+# Host spans of the serving loop on the profiler's clock. serve.step holds
+# serve.buddy_state, serve.launch, serve.readback and serve.account, in that
+# order; the others are the scheduler's, plus serve.sample.
+SERVE_SPANS = ("serve.step", "serve.buddy_state", "serve.launch",
+               "serve.readback", "serve.account", "serve.sample",
+               "serve.admit", "serve.batch", "serve.emit", "serve.feedback",
+               "serve.wait")
+# Metadata of serve.account: per-step counts over the valid tokens.
+STEP_COUNTERS = ("routed", "hits", "substituted", "fetched", "dropped",
+                 "degraded", "slots_routed", "slots_computed")
+
+# While a profiler trace runs, each finished host span is also kept here as
+# (name, start_s, end_s, depth, metadata), on ``time.perf_counter``'s clock,
+# so that a process can read its own spans without parsing the trace.
+HOST_SPAN_LOG: collections.deque = collections.deque(maxlen=1 << 18)
+_DEPTH = [0]          # open host spans (the serving loop is one thread)
+
+
+class host_span:
+    """One span of the serving loop's host work::
+
+        with host_span("serve.step", kind="chunk") as span:
+            ...
+            if span.on:
+                span.set(rows=3)
+
+    While a profiler trace runs it is a ``TraceAnnotation`` carrying
+    ``meta`` as its stats, and ``on`` is True; otherwise it records nothing.
+    Compute metadata only under ``span.on``, so the untraced path pays
+    nothing for it."""
+
+    __slots__ = ("name", "meta", "on", "_ann", "_t0", "_depth")
+
+    def __init__(self, name: str, **meta):
+        self.name = name
+        self.meta = meta
+        self.on = False
+
+    def __enter__(self) -> "host_span":
+        if TraceAnnotation.is_enabled():
+            self.on = True
+            self._ann = TraceAnnotation(self.name, **self.meta)
+            self._ann.__enter__()
+            self._depth = _DEPTH[0]
+            _DEPTH[0] += 1
+            self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **meta) -> None:
+        """Attach metadata to a running span (only where ``on``)."""
+        self.meta.update(meta)
+        self._ann.set_metadata(**meta)
+
+    def __exit__(self, *exc) -> bool:
+        if self.on:
+            t1 = time.perf_counter()
+            self._ann.__exit__(*exc)
+            _DEPTH[0] -= 1
+            HOST_SPAN_LOG.append((self.name, self._t0, t1, self._depth,
+                                  self.meta))
+        return False
 
 
 class FlightRecorder:

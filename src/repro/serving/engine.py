@@ -42,6 +42,7 @@ from repro.runtime.memory import (DEFAULT_HW, HardwareModel, TransferLedger,
 from repro.runtime.paged_kv import PagedKVPool
 from repro.runtime.telemetry import ExpertStats, Telemetry
 from repro.runtime.tiers import TIER_BITS, TieredExpertStore
+from repro.runtime.trace import host_span
 from repro.runtime.transfers import TransferScheduler, make_ici_links
 from repro.serving.prefix import PrefixTree
 
@@ -271,16 +272,16 @@ class ServeEngine:
             # buddy-aware eviction: prefer victims whose misses buddies absorb
             self.cache.buddy_table = self._table
 
-        self._step_fn = jax.jit(
-            functools.partial(transformer.decode_step, cfg=self.cfg,
-                              policy=self.policy, record=True,
-                              window=self.window),
-            static_argnames=())
-        self._chunk_fn = jax.jit(
-            functools.partial(transformer.prefill_chunk, cfg=self.cfg,
-                              policy=self.policy, record=True,
-                              window=self.window),
-            static_argnames=())
+        self._step_fn = self._jit_step(transformer.decode_step)
+        self._chunk_fn = self._jit_step(transformer.prefill_chunk)
+        self._launch = ("step", (1, 1))    # last launch: mode, token shape
+
+    def _jit_step(self, fn):
+        """``fn`` jitted with this engine's static arguments; the program
+        keeps ``fn``'s name (``jit_decode_step``, ``jit_prefill_chunk``)."""
+        step = functools.partial(fn, cfg=self.cfg, policy=self.policy,
+                                 record=True, window=self.window)
+        return jax.jit(functools.update_wrapper(step, fn))
 
     # ------------------------------------------------------------------
     def _build_peer_links(self) -> dict:
@@ -424,26 +425,33 @@ class ServeEngine:
         flow through the fixed-shape jitted graph, but are excluded from all
         expert-usage, transfer, and throughput accounting.
         Returns (logits [B, V], new_caches)."""
-        buddies = self._buddy_state()
-        self._key, sub = jax.random.split(self._key)
-        kw = {}
-        if self._paged:
-            b = int(token.shape[0])
-            # paged decode is always per-row: broadcast a lockstep scalar
-            pos = np.broadcast_to(np.asarray(pos, np.int32), (b,))
-            act = (np.ones(b, bool) if active is None
-                   else np.asarray(active, bool))
-            for i in np.flatnonzero(act):
-                p = int(pos[i])
-                self.kv_pool.ensure_range(i, p, p + 1)
-            caches = self._apply_kv_copies(caches)
-            kw["block_tables"] = jnp.asarray(self.kv_pool.tables)
-        logits, caches, aux = self._step_fn(
-            params=self.params, token=token, caches=caches,
-            pos=jnp.asarray(pos, jnp.int32), buddies=buddies, rng=sub, **kw)
-        if active is None:
-            active = np.ones(int(token.shape[0]), bool)
-        self._account(aux, active=np.asarray(active, bool))
+        b = int(token.shape[0])
+        with host_span("serve.step", kind="decode") as span:
+            active = (np.ones(b, bool) if active is None
+                      else np.asarray(active, bool))
+            if span.on:
+                n = int(active.sum())
+                span.set(rows=n, tokens=n)
+            with host_span("serve.buddy_state"):
+                buddies = self._buddy_state()
+            with host_span("serve.launch"):
+                self._key, sub = jax.random.split(self._key)
+                kw = {}
+                if self._paged:
+                    # paged decode is always per-row: broadcast a lockstep
+                    # scalar
+                    pos = np.broadcast_to(np.asarray(pos, np.int32), (b,))
+                    for i in np.flatnonzero(active):
+                        p = int(pos[i])
+                        self.kv_pool.ensure_range(i, p, p + 1)
+                    caches = self._apply_kv_copies(caches)
+                    kw["block_tables"] = jnp.asarray(self.kv_pool.tables)
+                logits, caches, aux = self._step_fn(
+                    params=self.params, token=token, caches=caches,
+                    pos=jnp.asarray(pos, jnp.int32), buddies=buddies,
+                    rng=sub, **kw)
+            self._launch = ("step", (b, 1))
+            self._account(aux, active=active)
         return logits, caches
 
     def prefill_rows(self, tokens, rows, caches, base_pos, tok_valid=None):
@@ -466,48 +474,94 @@ class ServeEngine:
 
         Returns (logits [B, C, V], new_caches); row i's next-token logits
         sit at index ``tok_valid[i].sum() - 1``."""
-        tokens = jnp.asarray(tokens, jnp.int32)
-        b, c = tokens.shape
-        rows = np.asarray(rows, bool)
-        if tok_valid is None:
-            tok_valid = np.repeat(rows[:, None], c, axis=1)
-        tok_valid = np.asarray(tok_valid, bool) & rows[:, None]
-        base = np.asarray(base_pos, np.int32)
-        counts = tok_valid.sum(axis=1)
-        kw = {}
-        if self._paged:
-            # no ring to wrap: a block's slot index IS its content position.
-            # Map/CoW the write range of every live row, land pending block
-            # copies, and ship the block table with the launch.
-            for i in np.flatnonzero(counts > 0):
-                self.kv_pool.ensure_range(int(i), int(base[i]),
-                                          int(base[i] + counts[i]))
-            caches = self._apply_kv_copies(caches)
-            kw["block_tables"] = jnp.asarray(self.kv_pool.tables)
-        else:
-            # ring-wrap guard: a multi-token chunk is scattered into the KV
-            # cache before its queries attend, so it must not wrap the ring
-            # buffer (attn_prefill_chunk); single-token rows are plain
-            # decode writes
-            cap = jax.tree.leaves(caches)[0].shape[2]
-            multi = counts > 1
-            assert not multi.any() or \
-                int((base[multi] + counts[multi]).max()) <= cap, \
-                "chunked prefill would wrap the KV ring buffer: size " \
-                "caches to the full prompt (prompt end %d > capacity %d)" % (
-                    int((base[multi] + counts[multi]).max()), cap)
-
-        buddies = self._buddy_state()
-        self._key, sub = jax.random.split(self._key)
-        logits, caches, aux = self._chunk_fn(
-            params=self.params, tokens=tokens, caches=caches,
-            base_pos=jnp.asarray(base, jnp.int32),
-            tok_valid=jnp.asarray(tok_valid), buddies=buddies, rng=sub, **kw)
-        self._account(aux, active=tok_valid.reshape(-1))
+        with host_span("serve.step", kind="chunk") as span:
+            tokens = jnp.asarray(tokens, jnp.int32)
+            b, c = tokens.shape
+            rows = np.asarray(rows, bool)
+            if tok_valid is None:
+                tok_valid = np.repeat(rows[:, None], c, axis=1)
+            tok_valid = np.asarray(tok_valid, bool) & rows[:, None]
+            base = np.asarray(base_pos, np.int32)
+            counts = tok_valid.sum(axis=1)
+            if span.on:
+                span.set(rows=int((counts > 0).sum()),
+                         tokens=int(counts.sum()))
+            with host_span("serve.buddy_state"):
+                buddies = self._buddy_state()
+            with host_span("serve.launch"):
+                kw = {}
+                if self._paged:
+                    # no ring to wrap: a block's slot index IS its content
+                    # position. Map/CoW the write range of every live row,
+                    # land pending block copies, and ship the block table
+                    # with the launch.
+                    for i in np.flatnonzero(counts > 0):
+                        self.kv_pool.ensure_range(int(i), int(base[i]),
+                                                  int(base[i] + counts[i]))
+                    caches = self._apply_kv_copies(caches)
+                    kw["block_tables"] = jnp.asarray(self.kv_pool.tables)
+                else:
+                    # ring-wrap guard: a multi-token chunk is scattered into
+                    # the KV cache before its queries attend, so it must not
+                    # wrap the ring buffer (attn_prefill_chunk); single-token
+                    # rows are plain decode writes
+                    cap = jax.tree.leaves(caches)[0].shape[2]
+                    multi = counts > 1
+                    assert not multi.any() or \
+                        int((base[multi] + counts[multi]).max()) <= cap, \
+                        "chunked prefill would wrap the KV ring buffer: " \
+                        "size caches to the full prompt (prompt end %d > " \
+                        "capacity %d)" % (
+                            int((base[multi] + counts[multi]).max()), cap)
+                self._key, sub = jax.random.split(self._key)
+                logits, caches, aux = self._chunk_fn(
+                    params=self.params, tokens=tokens, caches=caches,
+                    base_pos=jnp.asarray(base, jnp.int32),
+                    tok_valid=jnp.asarray(tok_valid), buddies=buddies,
+                    rng=sub, **kw)
+            self._launch = ("chunk", (b, c))
+            self._account(aux, active=tok_valid.reshape(-1))
         return logits, caches
 
     # -- per-layer step timeline ---------------------------------------
     def _account(self, aux, active: np.ndarray) -> None:
+        """Read the step's routing record back in one transfer (the wait on
+        the device; ``aux["recorded"]`` then holds host copies), and replay
+        the step on the transfer timeline. While a profiler trace runs, the
+        ``serve.account`` span carries the step's counters."""
+        with host_span("serve.readback"):
+            aux["recorded"] = jax.device_get(aux.get("recorded", []))
+        with host_span("serve.account") as span:
+            n_hit = self.stats.n_hit
+            self._replay(aux["recorded"], active)
+            if span.on:
+                span.set(hits=self.stats.n_hit - n_hit,
+                         **self._step_counters(aux["recorded"], active))
+
+    def _step_counters(self, recorded, active: np.ndarray) -> dict:
+        """The step's counts over its valid tokens: distinct (layer, expert)
+        pairs routed, miss outcomes in slots summed over layers, and the
+        slots routed against the slots the dispatch computed."""
+        out = dict(routed=0, substituted=0, fetched=0, dropped=0,
+                   degraded=0)
+        fetch = self.policy.fallback == "fetch"
+        for rec in recorded:
+            idx = rec["indices"][:, active]                   # [L, T, K]
+            out["routed"] += sum(len(np.unique(li)) for li in idx)
+            out["substituted"] += int(rec["substituted"][:, active].sum())
+            n_miss = int(rec["missed"][:, active].sum())
+            out["fetched" if fetch else "dropped"] += n_miss
+            for key in ("dropped", "degraded"):
+                if key in rec:
+                    out[key] += int(rec[key][:, active].sum())
+        mode, shape = self._launch
+        out["slots_routed"] = (int(active.sum()) * self.cfg.moe.top_k
+                               * self.num_moe_layers)
+        out["slots_computed"] = transformer.moe_slots_computed(
+            self.cfg, self.policy, mode, shape)
+        return out
+
+    def _replay(self, recorded, active: np.ndarray) -> None:
         """Replay the step on the transfer timeline, layer by layer.
         ``active`` is a flat [T] TOKEN mask (T = B for decode steps, B*C
         row-major for chunk steps) — pad rows (StaticBatcher), empty decode
@@ -534,7 +588,7 @@ class ServeEngine:
 
         layer_off = 0
         e_n = self.cfg.moe.num_experts
-        for rec in aux.get("recorded", []):
+        for rec in recorded:
             idx = np.asarray(rec["indices"])                  # [L, T, K]
             sub_sl = np.asarray(rec["substituted"])           # [L, T, K]
             miss_sl = np.asarray(rec["missed"])               # [L, T, K]
@@ -1066,12 +1120,13 @@ class ServeEngine:
     def sample_tokens(self, logits, greedy: bool, temperature: float = 1.0):
         """Next-token choice from [B, V] logits: argmax, or seeded temperature
         sampling from the engine's PRNG stream (greedy=False)."""
-        if greedy:
-            return np.asarray(jnp.argmax(logits, axis=-1))
-        assert temperature > 0.0, "temperature must be > 0 for sampling"
-        self._key, sub = jax.random.split(self._key)
-        scaled = logits.astype(jnp.float32) / temperature
-        return np.asarray(jax.random.categorical(sub, scaled, axis=-1))
+        with host_span("serve.sample"):
+            if greedy:
+                return np.asarray(jnp.argmax(logits, axis=-1))
+            assert temperature > 0.0, "temperature must be > 0 for sampling"
+            self._key, sub = jax.random.split(self._key)
+            scaled = logits.astype(jnp.float32) / temperature
+            return np.asarray(jax.random.categorical(sub, scaled, axis=-1))
 
     def generate(self, prompts: np.ndarray, max_new_tokens: int,
                  greedy: bool = True, temperature: float = 1.0,

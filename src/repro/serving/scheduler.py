@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.runtime.prefetch import AdaptiveBudgetController
+from repro.runtime.trace import host_span
 
 # Request lifecycle states
 WAITING = "waiting"
@@ -507,38 +508,39 @@ class ContinuousScheduler:
     def _admit(self, queue: RequestQueue, slot, pos, tok, caches):
         """Fill free slots from the backlog at the current clock; reset the
         decode caches of reused rows. Returns (caches, active mask)."""
-        eng = self.engine
-        now = eng.scheduler.now
-        newly = []
-        for i in range(self.slots):
-            if slot[i] is not None:
-                continue
-            r = queue.pop(now,
-                          lambda rq: self._est_service(rq, self._est_step_s))
-            if r is None:
-                break
-            r.state = RUNNING
-            r.admitted_s = now
-            r.cursor = 1
-            slot[i] = r
-            pos[i] = 0
-            tok[i] = int(r.prompt[0])
-            newly.append(i)
-        if newly:
-            caches = eng.reset_rows(caches, newly)
-        if getattr(eng, "prefix_tree", None) is not None:
-            # radix-cache admission: adopt the longest cached prefix and
-            # start prefill at the first novel token — a full-prefix hit
-            # leaves exactly one token to feed (near-zero TTFT)
-            for i in newly:
-                r = slot[i]
-                m = eng.adopt_prefix(i, r.prompt)
-                if m > 0:
-                    r.prefix_hit_tokens = m
-                    pos[i] = m
-                    tok[i] = int(r.prompt[m])
-                    r.cursor = m + 1
-        return caches, np.array([s is not None for s in slot], bool)
+        with host_span("serve.admit"):
+            eng = self.engine
+            now = eng.scheduler.now
+            newly = []
+            for i in range(self.slots):
+                if slot[i] is not None:
+                    continue
+                r = queue.pop(now, lambda rq: self._est_service(
+                    rq, self._est_step_s))
+                if r is None:
+                    break
+                r.state = RUNNING
+                r.admitted_s = now
+                r.cursor = 1
+                slot[i] = r
+                pos[i] = 0
+                tok[i] = int(r.prompt[0])
+                newly.append(i)
+            if newly:
+                caches = eng.reset_rows(caches, newly)
+            if getattr(eng, "prefix_tree", None) is not None:
+                # radix-cache admission: adopt the longest cached prefix and
+                # start prefill at the first novel token — a full-prefix hit
+                # leaves exactly one token to feed (near-zero TTFT)
+                for i in newly:
+                    r = slot[i]
+                    m = eng.adopt_prefix(i, r.prompt)
+                    if m > 0:
+                        r.prefix_hit_tokens = m
+                        pos[i] = m
+                        tok[i] = int(r.prompt[m])
+                        r.cursor = m + 1
+            return caches, np.array([s is not None for s in slot], bool)
 
     def _tick(self, t0: float, n_active: int) -> float:
         """Post-step bookkeeping: refine the EWMA step estimate, count the
@@ -604,16 +606,17 @@ class ContinuousScheduler:
         controller rides the same step loop: its tick is interval-gated on
         the simulated clock, so calling it here AND from the engine's
         step accounting never double-fires a window."""
-        if self.controller is not None:
-            self.controller.observe_step(
-                self.engine.stall_breakdown(),
-                queue.depth(self.engine.scheduler.now),
-                worthwhile=getattr(self.engine,
-                                   "last_prefetch_worthwhile", None))
-            self.controller.apply(self.engine)
-        placement = getattr(self.engine, "placement", None)
-        if placement is not None:
-            placement.maybe_tick(self.engine)
+        with host_span("serve.feedback"):
+            if self.controller is not None:
+                self.controller.observe_step(
+                    self.engine.stall_breakdown(),
+                    queue.depth(self.engine.scheduler.now),
+                    worthwhile=getattr(self.engine,
+                                       "last_prefetch_worthwhile", None))
+                self.controller.apply(self.engine)
+            placement = getattr(self.engine, "placement", None)
+            if placement is not None:
+                placement.maybe_tick(self.engine)
 
     def run(self, queue: RequestQueue,
             max_context: Optional[int] = None) -> dict:
@@ -634,7 +637,8 @@ class ContinuousScheduler:
         while self.steps < self.max_steps:
             caches, active = self._admit(queue, slot, pos, tok, caches)
             if not active.any():
-                nxt = queue.next_arrival()
+                with host_span("serve.wait"):
+                    nxt = queue.next_arrival()
                 if nxt is None:
                     break                       # drained: all work done
                 eng.advance_clock(max(eng.scheduler.now, nxt))
@@ -642,23 +646,25 @@ class ContinuousScheduler:
 
             # ---- one fused step: prefill + decode rows together -------
             t0 = eng.scheduler.now
-            logits, caches = eng.step(jnp.asarray(tok, jnp.int32), caches,
-                                      pos.copy(), active=active)
-            t1 = self._tick(t0, int(active.sum()))
-
+            with host_span("serve.batch"):
+                step_tok = jnp.asarray(tok, jnp.int32)
+            logits, caches = eng.step(step_tok, caches, pos.copy(),
+                                      active=active)
             sampled = eng.sample_tokens(logits, self.greedy, self.temperature)
-            for i in range(b):
-                r = slot[i]
-                if r is None:
-                    continue
-                pos[i] += 1
-                if r.cursor < len(r.prompt):    # still prefilling this row
-                    tok[i] = int(r.prompt[r.cursor])
-                    r.cursor += 1
-                    continue
-                if pos[i] == len(r.prompt):     # prefill just completed
-                    self._maybe_insert_prefix(i, r)
-                self._emit(slot, i, int(sampled[i]), t1, tok)
+            with host_span("serve.emit"):
+                t1 = self._tick(t0, int(active.sum()))
+                for i in range(b):
+                    r = slot[i]
+                    if r is None:
+                        continue
+                    pos[i] += 1
+                    if r.cursor < len(r.prompt):    # still prefilling
+                        tok[i] = int(r.prompt[r.cursor])
+                        r.cursor += 1
+                        continue
+                    if pos[i] == len(r.prompt):     # prefill just completed
+                        self._maybe_insert_prefix(i, r)
+                    self._emit(slot, i, int(sampled[i]), t1, tok)
             self._feedback(queue)
 
         return self.summary(queue, t_start)
@@ -684,7 +690,8 @@ class ContinuousScheduler:
         while self.steps < self.max_steps:
             caches, active = self._admit(queue, slot, pos, tok, caches)
             if not active.any():
-                nxt = queue.next_arrival()
+                with host_span("serve.wait"):
+                    nxt = queue.next_arrival()
                 if nxt is None:
                     break                       # drained: all work done
                 eng.advance_clock(max(eng.scheduler.now, nxt))
@@ -692,50 +699,56 @@ class ContinuousScheduler:
 
             # ---- one fused step: prefill chunks + decode rows ---------
             t0 = eng.scheduler.now
-            n_feed = np.zeros(b, np.int32)
-            prefilling = any(slot[i] is not None
-                             and pos[i] < len(slot[i].prompt)
-                             for i in range(b))
+            with host_span("serve.batch"):
+                n_feed = np.zeros(b, np.int32)
+                prefilling = any(slot[i] is not None
+                                 and pos[i] < len(slot[i].prompt)
+                                 for i in range(b))
+                if prefilling:
+                    chunk = self._effective_chunk(slot, pos)
+                    tokens = np.zeros((b, chunk), np.int64)
+                    valid = np.zeros((b, chunk), bool)
+                    for i in range(b):
+                        r = slot[i]
+                        if r is None:
+                            continue
+                        if pos[i] < len(r.prompt):
+                            n = min(chunk, len(r.prompt) - pos[i])
+                            tokens[i, :n] = r.prompt[pos[i]:pos[i] + n]
+                        else:
+                            n = 1
+                            tokens[i, 0] = tok[i]
+                        valid[i, :n] = True
+                        n_feed[i] = n
+                    step_in = jnp.asarray(tokens, jnp.int32)
+                else:
+                    n_feed[active] = 1
+                    step_in = jnp.asarray(tok, jnp.int32)
             if prefilling:
-                chunk = self._effective_chunk(slot, pos)
-                tokens = np.zeros((b, chunk), np.int64)
-                valid = np.zeros((b, chunk), bool)
+                logits, caches = eng.prefill_rows(
+                    step_in, active, caches, base_pos=pos.copy(),
+                    tok_valid=valid)
+                with host_span("serve.batch"):
+                    step_logits = logits[jnp.arange(b),
+                                         jnp.maximum(n_feed - 1, 0)]
+            else:
+                step_logits, caches = eng.step(step_in, caches, pos.copy(),
+                                               active=active)
+
+            sampled = eng.sample_tokens(step_logits, self.greedy,
+                                        self.temperature)
+            with host_span("serve.emit"):
+                t1 = self._tick(t0, int(active.sum()))
                 for i in range(b):
                     r = slot[i]
                     if r is None:
                         continue
-                    if pos[i] < len(r.prompt):
-                        n = min(chunk, len(r.prompt) - pos[i])
-                        tokens[i, :n] = r.prompt[pos[i]:pos[i] + n]
-                    else:
-                        n = 1
-                        tokens[i, 0] = tok[i]
-                    valid[i, :n] = True
-                    n_feed[i] = n
-                logits, caches = eng.prefill_rows(
-                    jnp.asarray(tokens, jnp.int32), active, caches,
-                    base_pos=pos.copy(), tok_valid=valid)
-                step_logits = logits[jnp.arange(b),
-                                     jnp.maximum(n_feed - 1, 0)]
-            else:
-                n_feed[active] = 1
-                step_logits, caches = eng.step(
-                    jnp.asarray(tok, jnp.int32), caches, pos.copy(),
-                    active=active)
-            t1 = self._tick(t0, int(active.sum()))
-
-            sampled = eng.sample_tokens(step_logits, self.greedy,
-                                        self.temperature)
-            for i in range(b):
-                r = slot[i]
-                if r is None:
-                    continue
-                pos[i] += n_feed[i]
-                if pos[i] < len(r.prompt):      # still prefilling this row
-                    continue
-                if pos[i] == len(r.prompt):     # prefill just completed
-                    self._maybe_insert_prefix(i, r)
-                self._emit(slot, i, int(sampled[i]), t1, tok)
+                    pos[i] += n_feed[i]
+                    if pos[i] < len(r.prompt):      # still prefilling
+                        continue
+                    if pos[i] == len(r.prompt):     # prefill just completed
+                        self._maybe_insert_prefix(i, r)
+                    self._emit(slot, i, int(sampled[i]), t1, tok)
             self._feedback(queue)
 
         return self.summary(queue, t_start)
