@@ -1,6 +1,7 @@
-"""MoE layer: top-k router, capacity-based dispatch, expert SwiGLU compute,
-and the BuddyMoE substitution hook (the paper's runtime layer between the
-router and expert execution, §3.4).
+"""MoE layer: top-k router, expert dispatch (a grouped matmul over the
+routed slots wherever no slot can be dropped, capacity buffers where one
+can), expert SwiGLU compute, and the BuddyMoE substitution hook (the paper's
+runtime layer between the router and expert execution, §3.4).
 
 Expert parallelism model: experts are tensor-parallel over the `model` mesh
 axis (d_ff sharded); tokens are data-parallel. Dispatch is therefore local to
@@ -241,7 +242,9 @@ def dispatch_plan(policy: Optional[BuddyPolicy], tokens_shape, num_experts: int,
                   capacity_factor: float = 1.25) -> tuple:
     """Which dispatch ``moe_forward`` runs for tokens of ``tokens_shape``
     ([B, S] or [T]) and each expert's capacity: ("fused", cap),
-    ("gather", 0) or ("capacity", cap). Static: shapes and flags only."""
+    ("gather", 0), ("ragged", 0) where no slot can be dropped (every
+    expert's capacity would be a row's S*K slots), else ("capacity", cap).
+    Static: shapes and flags only."""
     t_n = math.prod(tokens_shape)
     one_token = len(tokens_shape) == 2 and tokens_shape[1] == 1
     if policy is not None and policy.use_fused_dispatch:
@@ -251,25 +254,30 @@ def dispatch_plan(policy: Optional[BuddyPolicy], tokens_shape, num_experts: int,
         return "fused", min(t_n * top_k, -(-cap // 8) * 8)
     if not dropless and one_token and t_n * top_k < num_experts:
         return "gather", 0
+    if dropless:
+        return "ragged", 0
     rows = tokens_shape[0] if len(tokens_shape) == 2 else 1
     s_k = t_n // rows * top_k
-    if dropless:
-        return "capacity", s_k
     cap = int(max(top_k, s_k / num_experts * capacity_factor))
-    return "capacity", min(s_k, -(-cap // 8) * 8)
+    cap = min(s_k, -(-cap // 8) * 8)
+    return ("ragged", 0) if cap == s_k else ("capacity", cap)
 
 
 def dispatch_slots(plan: tuple, tokens_shape, num_experts: int, top_k: int,
-                   use_kernel: bool = False) -> int:
+                   use_kernel: bool = False,
+                   live_tokens: Optional[int] = None) -> int:
     """Expert-FFN slots one layer's dispatch computes under ``plan``: every
     expert's capacity on every row on the capacity path, every (expert,
-    class) group's capacity in the grouped kernel, else one per slot."""
+    class) group's capacity in the grouped kernel, the ``live_tokens``' slots
+    (default: every token's) in the grouped matmul, else one per slot."""
     path, cap = plan
     if path == "capacity":
         rows = tokens_shape[0] if len(tokens_shape) == 2 else 1
         return rows * num_experts * cap
     if path == "fused" and use_kernel:
         return 2 * num_experts * cap
+    if path == "ragged" and live_tokens is not None:
+        return live_tokens * top_k
     return math.prod(tokens_shape) * top_k
 
 
@@ -279,13 +287,23 @@ def moe_forward(params: dict, x: jax.Array, cfg: MoEConfig, *,
                 capacity_factor: float = 1.25,
                 jitter_key=None,
                 use_kernel: bool = False,
-                dropless: bool = False) -> tuple:
+                dropless: bool = False,
+                live: Optional[jax.Array] = None,
+                layer: Any = 0) -> tuple:
     """x: [B, S, D] (or [T, D]). Returns (y, MoEAux).
 
-    ``dropless``: force the capacity-based dispatch path with capacity
-    S*K (no token ever dropped, no tiny-batch gather shortcut) — chunked
-    prefill needs per-token outputs independent of which other tokens share
-    the chunk, so C=1 and C=8 chunks produce identical per-token results.
+    ``dropless``: never drop a slot (the grouped matmul over every routed
+    slot, no tiny-batch gather shortcut) — chunked prefill needs per-token
+    outputs independent of which other tokens share the chunk, so C=1 and
+    C=8 chunks produce identical per-token results.
+
+    On the ragged plan (``dispatch_plan``) the expert FFN is one grouped
+    matmul over the routed slots sorted by expert. ``live`` ([B, S] bool,
+    default every token) marks the tokens whose slots it computes: the
+    others' expert outputs are zero. ``params["w1"]`` / ``w3`` / ``w2`` may
+    then be a stack of layers' tables flattened to [L*E, ...], and
+    ``layer`` the index of this layer's block in it, so the matmul reads
+    the experts in place.
 
     Tiered degraded fallback: when ``policy.quant_tier`` is on, the params
     carry a ``quant`` sub-dict, and ``buddy.quant_ok`` marks an expert, a
@@ -348,6 +366,10 @@ def moe_forward(params: dict, x: jax.Array, cfg: MoEConfig, *,
         elif path == "gather":
             yk = _gather_dispatch(params, x_flat, new_idx, degraded,
                                   run_degraded).reshape(t_n, k_n, d)
+            n_dropped = jnp.zeros((), jnp.int32)
+        elif path == "ragged":
+            yk = _ragged_dispatch(params, x_flat, new_idx, live, layer,
+                                  degraded, run_degraded, e_n)
             n_dropped = jnp.zeros((), jnp.int32)
         else:
             yk, n_dropped = _capacity_dispatch(
@@ -435,10 +457,51 @@ def _gather_dispatch(params, x_flat, new_idx, degraded, run_degraded):
     return y_rep
 
 
+def _ragged_dispatch(params, x_flat, new_idx, live, layer, degraded,
+                     run_degraded, num_experts: int):
+    """Dropless dispatch as one grouped matmul: per-slot outputs [T, K, D].
+
+    Each live slot goes to group ``layer * E + expert`` of the tables'
+    [G, ...] stack; the slots are sorted by group (stable) and
+    ``jax.lax.ragged_dot`` runs the three SwiGLU matmuls over the groups'
+    contiguous rows, reading each expert's weights in place. Slots of
+    tokens that are not ``live`` sort past the last group and are never
+    computed: their outputs are zero."""
+    t_n, d = x_flat.shape
+    k_n = new_idx.shape[1]
+    g_n = params["w1"].shape[0]
+    gid = new_idx.reshape(-1) + layer * num_experts                 # [N]
+    if live is not None:
+        gid = jnp.where(jnp.repeat(live.reshape(-1), k_n), gid, g_n)
+    order = jnp.argsort(gid, stable=True)
+    sizes = jnp.bincount(gid, length=g_n).astype(jnp.int32)         # [G]
+    xs = x_flat[order // k_n]                                       # [N, D]
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, params["w1"], sizes,
+                                       preferred_element_type=jnp.float32))
+    g = jax.lax.ragged_dot(xs, params["w3"], sizes,
+                           preferred_element_type=jnp.float32)
+    hg = (h * g).astype(x_flat.dtype)
+    hg = shard(hg, None, "dff")
+    ys = jax.lax.ragged_dot(hg, params["w2"], sizes,
+                            preferred_element_type=jnp.float32)
+    # rows past the last group are left unspecified by the grouped matmul
+    computed = jnp.arange(ys.shape[0]) < sizes.sum()
+    ys = jnp.where(computed[:, None], ys, 0.0).astype(x_flat.dtype)
+    yk = jnp.zeros_like(ys).at[order].set(ys, unique_indices=True)
+    yk = yk.reshape(t_n, k_n, d)
+    if run_degraded:
+        y_deg = _degraded_outputs(params["quant"], x_flat,
+                                  new_idx.reshape(-1))
+        yk = jnp.where(degraded[..., None],
+                       y_deg.reshape(t_n, k_n, d).astype(x_flat.dtype), yk)
+    return yk
+
+
 def _capacity_dispatch(params, x, x_flat, new_idx, degraded, run_degraded,
                        use_kernel: bool, cap: int):
-    """Capacity-based dispatch (row-local): per-slot outputs [T, K, D] and
-    the count of slots past an expert's capacity.
+    """Capacity-based dispatch (row-local), for plans that can drop a slot
+    (``cap`` < S*K): per-slot outputs [T, K, D] and the count of slots past
+    an expert's capacity.
 
     Dispatch independently per batch row so that with the batch sharded
     over `data` the scatter/gather and expert compute are collective-free

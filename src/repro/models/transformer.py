@@ -133,9 +133,11 @@ class StepCtx(NamedTuple):
     rng: Any                       # router jitter key or None
     record: bool
     remat: bool = False            # checkpoint each scanned block (training)
-    tok_valid: Any = None          # [B, C] prefix validity mask (chunk mode)
+    tok_valid: Any = None          # [B, C] prefix validity mask (chunk mode),
+    #                                or [B, 1] live rows (step mode)
     block_tables: Any = None       # [B, MB] paged-KV block table (None = ring)
     dropless: bool = False         # full mode: no expert capacity limit
+    layer: Any = 0                 # the block's index in its group's stack
 
 
 def _attn_kwargs(cfg: ModelConfig):
@@ -171,17 +173,28 @@ def _moe_dispatch_args(mode: str, dropless: bool = False) -> dict:
             "dropless": dropless or mode == "chunk"}
 
 
+def _moe_plan(cfg: ModelConfig, policy: Optional[BuddyPolicy], mode: str,
+              tokens_shape, dropless: bool = False) -> tuple:
+    """The dispatch plan ``moe_forward`` takes for tokens of
+    ``tokens_shape`` in ``mode``."""
+    return moe_mod.dispatch_plan(policy, tokens_shape, cfg.moe.num_experts,
+                                 cfg.moe.top_k,
+                                 **_moe_dispatch_args(mode, dropless))
+
+
 def moe_slots_computed(cfg: ModelConfig, policy: Optional[BuddyPolicy],
-                       mode: str, tokens_shape) -> int:
+                       mode: str, tokens_shape,
+                       n_live: Optional[int] = None) -> int:
     """Expert-FFN slots one serving step's dispatch computes, summed over
     the MoE layers: ``tokens_shape`` is [B, 1] for a decode step ("step")
     and [B, C] for a prefill chunk ("chunk"); the path is the one
-    ``moe_forward`` takes for it."""
+    ``moe_forward`` takes for it. The grouped matmul computes only the
+    step's ``n_live`` live tokens' slots."""
     e, k = cfg.moe.num_experts, cfg.moe.top_k
-    plan = moe_mod.dispatch_plan(policy, tokens_shape, e, k,
-                                 **_moe_dispatch_args(mode))
+    plan = _moe_plan(cfg, policy, mode, tokens_shape)
     n_moe = sum(r for kind, r in cfg.stack() if kind == ATTN_MOE)
-    return n_moe * moe_mod.dispatch_slots(plan, tokens_shape, e, k)
+    return n_moe * moe_mod.dispatch_slots(plan, tokens_shape, e, k,
+                                          live_tokens=n_live)
 
 
 def _zero_moe_aux(cfg: ModelConfig):
@@ -230,8 +243,8 @@ def block_forward(kind: str, p, x, cache, ctx: StepCtx, buddy=None,
         if kind == ATTN_MOE:
             y, moe_aux = moe_mod.moe_forward(
                 p["moe"], xn, cfg.moe, policy=ctx.policy, buddy=buddy,
-                jitter_key=ctx.rng, **_moe_dispatch_args(ctx.mode,
-                                                         ctx.dropless))
+                jitter_key=ctx.rng, live=ctx.tok_valid, layer=ctx.layer,
+                **_moe_dispatch_args(ctx.mode, ctx.dropless))
             aux = _moe_aux_dict(cfg, moe_aux, ctx.record)
         else:
             y = swiglu(xn, p["ffn"]["w1"], p["ffn"]["w3"], p["ffn"]["w2"])
@@ -342,11 +355,27 @@ def _run_group(kind: str, gparams, x, gcache, ctx: StepCtx, gbuddy=None,
         x, new_caches = jax.lax.scan(body, x, (gparams, gcache))
         return x, new_caches, _zero_moe_aux(cfg)
 
-    # homogeneous group
+    # homogeneous group. On the ragged MoE plan the expert tables stay out
+    # of the scanned inputs: the grouped matmul reads the whole stack,
+    # flattened to [R*E, ...] (a bitcast), at the layer's offset, so no
+    # per-layer copy of a table is made.
+    tables = {}
+    if kind == ATTN_MOE and \
+            _moe_plan(cfg, ctx.policy, ctx.mode, x.shape[:-1],
+                      ctx.dropless)[0] == "ragged":
+        moe_p = gparams["moe"]
+        tables = {n: moe_p[n].reshape((-1,) + moe_p[n].shape[2:])
+                  for n in ("w1", "w3", "w2")}
+        gparams = {**gparams, "moe": {n: v for n, v in moe_p.items()
+                                      if n not in tables}}
+
     def body(carry, inp):
         x, rng = carry
         lp, lc, lb, li = inp
-        lctx = ctx._replace(rng=jax.random.fold_in(rng, li) if rng is not None else None)
+        lctx = ctx._replace(rng=jax.random.fold_in(rng, li) if rng is not None else None,
+                            layer=li)
+        if tables:
+            lp = {**lp, "moe": {**lp["moe"], **tables}}
         x, nc, aux = block_forward(kind, lp, x, lc, lctx, buddy=lb)
         if aux is None:
             aux = _zero_moe_aux(cfg)
@@ -546,13 +575,14 @@ def forward_train(params, cfg: ModelConfig, tokens, *, cond_embeds=None,
 def decode_step(params, cfg: ModelConfig, token, caches, pos, *,
                 cond_embeds=None, policy: Optional[BuddyPolicy] = None,
                 buddies=None, rng=None, window: int = -1,
-                record: bool = False, block_tables=None):
+                record: bool = False, block_tables=None, tok_valid=None):
     """One-token decode. token [B] int32; pos int32 — a scalar (lockstep
     batch) or a [B] vector of per-row absolute positions (continuous
     batching), including any audio conditioning prefix. block_tables
     [B, MB] routes attention through the paged-KV pool instead of the
-    per-row ring (pos must then be [B]). Returns
-    (logits [B, V], new_caches, aux)."""
+    per-row ring (pos must then be [B]). tok_valid [B] bool marks the live
+    rows (default: every row); the MoE grouped matmul skips the others'
+    slots. Returns (logits [B, V], new_caches, aux)."""
     if window < 0:
         window = cfg.sliding_window
     with jax.named_scope("embed"):
@@ -560,6 +590,7 @@ def decode_step(params, cfg: ModelConfig, token, caches, pos, *,
     if cfg.family == "audio" and cfg.num_cond_tokens:
         pos = pos + cfg.num_cond_tokens
     ctx = StepCtx(cfg, "step", window, policy, pos, rng, record,
+                  tok_valid=None if tok_valid is None else tok_valid[:, None],
                   block_tables=block_tables)
 
     total_aux = _zero_moe_aux(cfg)

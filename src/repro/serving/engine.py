@@ -449,7 +449,7 @@ class ServeEngine:
                 logits, caches, aux = self._step_fn(
                     params=self.params, token=token, caches=caches,
                     pos=jnp.asarray(pos, jnp.int32), buddies=buddies,
-                    rng=sub, **kw)
+                    rng=sub, tok_valid=jnp.asarray(active), **kw)
             self._launch = ("step", (b, 1))
             self._account(aux, active=active)
         return logits, caches
@@ -558,7 +558,7 @@ class ServeEngine:
         out["slots_routed"] = (int(active.sum()) * self.cfg.moe.top_k
                                * self.num_moe_layers)
         out["slots_computed"] = transformer.moe_slots_computed(
-            self.cfg, self.policy, mode, shape)
+            self.cfg, self.policy, mode, shape, n_live=int(active.sum()))
         return out
 
     def _replay(self, recorded, active: np.ndarray) -> None:

@@ -146,14 +146,22 @@ def test_account_counters_match_stats_and_masks(setup, chunk, slots,
 
 def test_slots_computed_follows_the_dispatch_path():
     pol = BuddyPolicy()
-    # capacity path: every expert's capacity on every row
-    assert moe_mod.dispatch_plan(pol, (16, 8), 64, 6, dropless=True) == \
-        ("capacity", 48)
-    assert moe_mod.dispatch_slots(("capacity", 48), (16, 8), 64, 6) == \
-        16 * 64 * 48
-    # decode at 16 rows: capacity K per expert and row
+    # dropless chunk: the grouped matmul over the live tokens' slots
+    plan = moe_mod.dispatch_plan(pol, (16, 8), 64, 6, dropless=True)
+    assert plan == ("ragged", 0)
+    assert moe_mod.dispatch_slots(plan, (16, 8), 64, 6) == 16 * 8 * 6
+    assert moe_mod.dispatch_slots(plan, (16, 8), 64, 6, live_tokens=30) \
+        == 30 * 6
+    # capacity path, where a slot can be dropped: every expert's capacity
+    # on every row
+    plan = moe_mod.dispatch_plan(pol, (16, 128), 64, 6)
+    assert plan == ("capacity", 16)
+    assert moe_mod.dispatch_slots(plan, (16, 128), 64, 6) == 16 * 64 * 16
+    # decode at 16 rows: capacity K = S*K, so nothing can drop
     plan = moe_mod.dispatch_plan(pol, (16, 1), 64, 6, capacity_factor=2.0)
-    assert plan == ("capacity", 6)
+    assert plan == ("ragged", 0)
+    assert moe_mod.dispatch_slots(plan, (16, 1), 64, 6, live_tokens=9) \
+        == 9 * 6
     # one row of top-2 over 8 experts: the active-expert gather
     plan = moe_mod.dispatch_plan(pol, (1, 1), 8, 2, capacity_factor=2.0)
     assert plan == ("gather", 0)
@@ -165,9 +173,15 @@ def test_slots_computed_follows_the_dispatch_path():
     assert moe_mod.dispatch_slots(plan, (4, 8), 64, 6, use_kernel=True) \
         == 2 * 64 * 4 * 8 * 6
     cfg = reduced()
-    n_moe = cfg.num_layers
-    assert transformer.moe_slots_computed(cfg, pol, "chunk", (3, 4)) == \
-        n_moe * 3 * 4 * 4 * 2
+    n_moe, k = cfg.num_layers, cfg.moe.top_k
+    # chunk and decode steps compute their live tokens' slots
+    assert transformer.moe_slots_computed(cfg, pol, "chunk", (3, 4),
+                                          n_live=5) == n_moe * 5 * k
+    assert transformer.moe_slots_computed(cfg, pol, "step", (3, 1),
+                                          n_live=2) == n_moe * 2 * k
+    # one decode row: the gather path computes its slots whatever is live
+    assert transformer.moe_slots_computed(cfg, pol, "step", (1, 1),
+                                          n_live=0) == n_moe * 1 * k
 
 
 def test_step_programs_named_and_scoped(setup):

@@ -107,3 +107,131 @@ def test_lb_loss_uniform_router_is_one():
     _, aux = M.moe_forward(params, x, cfg, capacity_factor=4.0)
     # With a uniform router, E * sum(f_e * P_e) = E * E * (k/E) * (1/E) = k
     assert abs(float(aux.lb_loss) - cfg.top_k) < 0.2
+
+
+# ---------------------------------------------------------------------------
+# Ragged plan: the grouped matmul over the routed slots, sorted by expert
+# ---------------------------------------------------------------------------
+def _ragged_setup(shared=0, layers=1, seed=6, shape=(3, 8, 24)):
+    cfg = MoEConfig(num_experts=8, top_k=2, d_ff=32,
+                    num_shared_experts=shared)
+    key = jax.random.PRNGKey(seed)
+    ps = [M.init_moe(jax.random.fold_in(key, i), shape[-1], cfg, jnp.float32)
+          for i in range(layers)]
+    x = jax.random.normal(jax.random.fold_in(key, 99), shape) * 0.5
+    return cfg, ps, x
+
+
+@pytest.mark.parametrize("shared", [0, 1])
+def test_ragged_plan_matches_dense_reference(shared):
+    cfg, (params,), x = _ragged_setup(shared)
+    assert M.dispatch_plan(None, x.shape[:-1], cfg.num_experts, cfg.top_k,
+                           dropless=True) == ("ragged", 0)
+    y, aux = M.moe_forward(params, x, cfg, dropless=True)
+    assert int(aux.n_dropped) == 0
+    np.testing.assert_allclose(np.asarray(y),
+                               _dense_ref(params, np.asarray(x), cfg),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_ragged_per_slot_equals_capacity_dispatch(layer):
+    """Per-slot outputs at the first and last layer of a stacked group,
+    read at the layer's offset in the flattened [L*E, ...] tables, equal
+    the capacity dispatch's at capacity S*K on that layer's own tables."""
+    cfg, ps, x = _ragged_setup(layers=3)
+    e_n, k_n = cfg.num_experts, cfg.top_k
+    stacked = {n: jnp.concatenate([p[n] for p in ps]) for n in
+               ("w1", "w3", "w2")}
+    x_flat = x.reshape(-1, x.shape[-1])
+    _, idx, _, _ = M.router_topk(ps[layer]["router"], x_flat, k_n)
+    no = jnp.zeros(idx.shape, bool)
+    s_k = x.shape[1] * k_n
+    want, n_dropped = M._capacity_dispatch(ps[layer], x, x_flat, idx, no,
+                                           False, False, s_k)
+    got = M._ragged_dispatch(stacked, x_flat, idx, None, jnp.int32(layer),
+                             no, False, e_n)
+    assert int(n_dropped) == 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_ragged_skips_tokens_that_are_not_live():
+    """Slots of padding tokens are never computed (zero rows), and what the
+    padding holds leaves the live tokens' outputs as they are."""
+    cfg, (params,), x = _ragged_setup()
+    live = jnp.arange(x.shape[1])[None, :] < jnp.asarray([[3], [8], [0]])
+    noisy = jnp.where(live[..., None], x, 7.0 * x[::-1] + 3.0)
+    x_flat, n_flat = (a.reshape(-1, a.shape[-1]) for a in (x, noisy))
+    _, idx, _, _ = M.router_topk(params["router"], x_flat, cfg.top_k)
+    _, n_idx, _, _ = M.router_topk(params["router"], n_flat, cfg.top_k)
+    n_idx = jnp.where(live.reshape(-1, 1), idx, n_idx)
+    no = jnp.zeros(idx.shape, bool)
+    args = (None, jnp.int32(0), no, False, cfg.num_experts)
+    full = M._ragged_dispatch(params, x_flat, idx, *args)
+    got = M._ragged_dispatch(params, n_flat, n_idx, live, *args[1:])
+    lv = np.asarray(live.reshape(-1))
+    assert not np.asarray(got)[~lv].any()
+    np.testing.assert_array_equal(np.asarray(got)[lv], np.asarray(full)[lv])
+    # through moe_forward: a padding token's expert output is zero
+    y, _ = M.moe_forward(params, noisy, cfg, dropless=True, live=live)
+    assert not np.asarray(y)[~np.asarray(live)].any()
+
+
+def _chunked(params, x, cfg, chunk):
+    return jnp.concatenate(
+        [M.moe_forward(params, x[:, i:i + chunk], cfg, dropless=True)[0]
+         for i in range(0, x.shape[1], chunk)], axis=1)
+
+
+@pytest.mark.parametrize("chunk", [3, 8])
+def test_ragged_token_output_independent_of_chunk(chunk):
+    """Dropless: a token's output is the same in a chunk of 3 or 8 tokens
+    as alone (chunk 1)."""
+    cfg, (params,), x = _ragged_setup(shared=1)
+    np.testing.assert_allclose(np.asarray(_chunked(params, x, cfg, chunk)),
+                               np.asarray(_chunked(params, x, cfg, 1)),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_ragged_reads_each_layer_of_the_stack():
+    """Through the model: the scan hands the grouped matmul the whole stack
+    of tables and each layer's index; the logits equal the fused dispatch's,
+    which gathers every slot's weights from the layer's own tables."""
+    from repro.configs.deepseek_v2_lite_buddy import reduced
+    from repro.models import transformer
+    cfg = reduced()
+    assert cfg.num_layers > 1
+    params = transformer.init_params(cfg, jax.random.PRNGKey(7))
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 6), 0,
+                                cfg.vocab_size)
+    want, _ = transformer.forward_train(
+        params, cfg, tokens, dropless=True,
+        policy=BuddyPolicy(use_fused_dispatch=True))
+    got, _ = transformer.forward_train(params, cfg, tokens, dropless=True,
+                                       policy=BuddyPolicy())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_decode_step_skips_rows_that_are_not_live():
+    """A decode step's grouped matmul computes only the live rows' slots:
+    their logits and KV writes are those of a step where every row is live."""
+    from repro.configs.deepseek_v2_lite_buddy import reduced
+    from repro.models import transformer
+    cfg = reduced()
+    b = 4
+    assert transformer._moe_plan(cfg, None, "step", (b, 1))[0] == "ragged"
+    params = transformer.init_params(cfg, jax.random.PRNGKey(9))
+    caches = transformer.init_caches(cfg, b, 8)
+    token = jnp.asarray([3, 5, 7, 11], jnp.int32)
+    pos = jnp.zeros((b,), jnp.int32)
+    live = jnp.asarray([True, False, True, False])
+    want, want_c, _ = transformer.decode_step(params, cfg, token, caches, pos)
+    got, got_c, _ = transformer.decode_step(params, cfg, token, caches, pos,
+                                            tok_valid=live)
+    np.testing.assert_array_equal(np.asarray(got)[np.asarray(live)],
+                                  np.asarray(want)[np.asarray(live)])
+    for g, w in zip(jax.tree.leaves(got_c), jax.tree.leaves(want_c)):
+        np.testing.assert_array_equal(np.asarray(g)[:, np.asarray(live)],
+                                      np.asarray(w)[:, np.asarray(live)])
+    assert not np.allclose(np.asarray(got)[1], np.asarray(want)[1])
