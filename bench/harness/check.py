@@ -2,14 +2,19 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the longest,
-is run through the reference (``bench/reference/moe_decoder.py``), one
-layer at a time with the weights drawn again from the seed. The numbers
-compared:
+is run through the configuration's reference (``"reference"``, e.g.
+``bench/reference/moe_decoder.py``), one layer at a time with the weights
+drawn again from the seed by the configuration's builder
+(``harness/model.py``). An MoE layer runs the reference's ``layer`` with the
+program's recorded route of that MoE layer; a layer of another kind runs its
+``block``, which a reference defines only where its builder has such
+layers. The numbers compared:
 
 - ``token_gap``: the widest gap, over the sample's served tokens, by which
   a served token's reference logit lies below the reference's best at that
   position, over the spread (std) of the reference's logits there.
-- ``route_gap``: the same for the router, over every fed position and layer:
+- ``route_gap``: the same for the router, over every fed position and MoE
+  layer:
   how far the least of the program's kept experts lies below the
   reference's k-th best router logit, over the spread of the router logits.
 - ``decision_errors``: fed positions with no record, slots substituted that
@@ -36,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.model import layer_key, layer_weights, outer_key, outer_weights
+from harness.model import layer_key, outer_key
 from harness.spec import load_module
 from harness.traffic import seed_rng
 
@@ -74,7 +79,8 @@ def sample_requests(done: list, seed: int, tokens: int,
 
 def teacher_forced(rec_steps: list, req, n_layers: int):
     """Per fed position of ``req``: routed experts [S, L, K], substituted
-    [S, L, K], residency [S, L, E], and the count of decision errors."""
+    [S, L, K], residency [S, L, E], and the count of decision errors. ``L``
+    counts the MoE layers, which the program records."""
     s_len = len(req.prompt) + len(req.tokens) - 1
     route = sub = resid = None
     seen = np.zeros(s_len, bool)
@@ -112,18 +118,26 @@ def teacher_forced(rec_steps: list, req, n_layers: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _fns(ref_path: str, m_json: str):
+def _fns(ref_path: str, builder, m_json: str):
     """Jitted pieces of the reference, each compiled once: every sequence
-    is padded to one length, so no shape depends on a request."""
+    is padded to one length, so no shape depends on a request. The weights
+    are drawn by ``builder``'s ``layer_weights`` (one per layer kind) and
+    ``outer_weights``."""
     ref = load_module(ref_path, "reference_" + str(abs(hash(ref_path))))
     m = json.loads(m_json)
     fns = {"ref": ref,
-           "gen_layer": jax.jit(lambda k: layer_weights(k, m)),
-           "gen_outer": jax.jit(lambda k: outer_weights(k, m)),
+           "gen_outer": jax.jit(lambda k: builder.outer_weights(k, m)),
            "embed": jax.jit(lambda o, t: o["embed"][t])}
+    kinds = set(builder.layer_kinds(m))
+    for kind in kinds:
+        fns["gen_layer", kind] = jax.jit(
+            lambda k, kind=kind: builder.layer_weights(k, m, kind))
     for q8 in (False, True):
         fns["layer", q8] = jax.jit(
             lambda p, x, r, f, q8=q8: ref.layer(p, x, r, f, m, q8))
+        for kind in kinds - {"moe"}:
+            fns["block", kind, q8] = jax.jit(
+                lambda p, x, kind=kind, q8=q8: ref.block(p, x, m, kind, q8))
 
     def tail(o, x, nxt, xc=None):
         """Per position: the reference's best logit, its spread, its logit
@@ -146,17 +160,20 @@ def _pad(a, n, fill=0):
 
 
 def compare(cfg_file: dict, root, seed: int, reqs: list, rec_steps: list,
-            table: np.ndarray, control: bool = False,
+            table: np.ndarray, builder, control: bool = False,
             pad_to: int = 0) -> dict:
     """The compared numbers over the sampled requests ``reqs``; with
-    ``control`` also the int8 control's, under ``control_*``. Sequences
+    ``control`` also the int8 control's, under ``control_*``. ``table`` is
+    [MoE layers, E, R]; ``builder`` the configuration's
+    (``spec.load_builder``). Sequences
     are padded to ``pad_to`` (the traffic's longest request) so that one
     compile serves every run."""
     m = cfg_file["model"]
-    fns = _fns(str(root / cfg_file["reference"]),
+    fns = _fns(str(root / cfg_file["reference"]), builder,
                json.dumps(m, sort_keys=True))
     ref = fns["ref"]
-    n_l = m["num_layers"]
+    kinds = builder.layer_kinds(m)
+    n_moe = kinds.count("moe")
     out = {"token_gap": 0.0, "route_gap": 0.0, "decision_errors": 0,
            "token_gap_mean": 0.0, "route_gap_mean": 0.0, "substituted": 0,
            "requests": len(reqs), "tokens": 0, "positions": 0}
@@ -167,7 +184,7 @@ def compare(cfg_file: dict, root, seed: int, reqs: list, rec_steps: list,
                                            "route_gap_mean")})
     data = []
     for r in reqs:
-        tf = teacher_forced(rec_steps, r, n_l)
+        tf = teacher_forced(rec_steps, r, n_moe)
         if tf is None:
             out["decision_errors"] += 1
             continue
@@ -195,13 +212,20 @@ def compare(cfg_file: dict, root, seed: int, reqs: list, rec_steps: list,
             x = fns["embed"](o, jnp.asarray(_pad(d["seq"][:-1], s_pad)))
             d["x"] = {pre: x for pre, _ in sides}
         del o
-        for l in range(n_l):
-            w = fns["gen_layer"](layer_key(seed, l))
+        for l, kind in enumerate(kinds):
+            w = fns["gen_layer", kind](layer_key(seed, l))
+            if kind != "moe":
+                for d in data:
+                    for pre, q8 in sides:
+                        d["x"][pre] = fns["block", kind, q8](w, d["x"][pre])
+                del w
+                continue
+            i = kinds[:l].count("moe")      # the program records MoE layers
             for d in data:
-                route = jnp.asarray(d["route"][:, l])
-                final, bad = ref.pick_buddies(d["route"][:, l],
-                                              d["sub"][:, l],
-                                              d["resid"][:, l], table[l])
+                route = jnp.asarray(d["route"][:, i])
+                final, bad = ref.pick_buddies(d["route"][:, i],
+                                              d["sub"][:, i],
+                                              d["resid"][:, i], table[i])
                 out["decision_errors"] += bad
                 final = jnp.asarray(final)
                 logits = None
@@ -229,7 +253,8 @@ def compare(cfg_file: dict, root, seed: int, reqs: list, rec_steps: list,
                                              float(gap.max()))
                 sums[pre][1] += float(gap.sum())
     for pre, _ in sides:
-        out[pre + "route_gap_mean"] = sums[pre][0] / (out["positions"] * n_l)
+        out[pre + "route_gap_mean"] = sums[pre][0] / (out["positions"]
+                                                      * n_moe)
         out[pre + "token_gap_mean"] = sums[pre][1] / out["tokens"]
     return out
 

@@ -1,5 +1,7 @@
 """Operations of the model's forward pass per processed token, counted once
-from the configuration's sizes (a multiply-add is two operations)."""
+from the configuration's sizes (a multiply-add is two operations).
+``token_flops`` is the default builder's (``harness/model.py``); a builder
+of another architecture gives its own, and ``chunk_flops`` sums it."""
 from __future__ import annotations
 
 
@@ -19,8 +21,10 @@ def token_flops(m: dict, context: int) -> float:
         + 2 * d * m["vocab_size"]
 
 
-def chunk_flops(m: dict, base: int, n: int) -> float:
-    """``n`` tokens fed at positions base .. base+n-1."""
+def chunk_flops(m: dict, base: int, n: int, token_flops=token_flops) -> float:
+    """``n`` tokens fed at positions base .. base+n-1. ``token_flops`` is
+    the builder's; it is taken as linear in the context, unless the model
+    has a ``sliding_window``, where each token is counted on its own."""
     fixed = token_flops(m, 0)
     per_key = token_flops(m, 1) - fixed
     if m.get("sliding_window"):

@@ -1,11 +1,28 @@
-"""The system under test, built from a configuration file and ``--seed``.
+"""The system under test, built from a configuration file and ``--seed``:
+the default builder, for a stack of identical attention + MoE blocks.
 
-The weights and the buddy tables are the benchmark's own, drawn from the
-seed, so that the plain reference can draw the same ones again without
-taking anything from the program. The weights are laid out as the program's
-``transformer.init_params`` lays them out and are made on the device in one
-jitted call; the reference makes them again one layer at a time
-(``layer_weights``), bit for bit the same.
+A configuration file may name a builder of its own (``"builder":
+"bench/<path>.py"``, loaded by ``spec.load_builder``); one that names none is
+built by this module. A builder gives:
+
+- ``model_config(cfg_file)``: the program's ``ModelConfig``;
+- ``layer_kinds(m)``: the stack in order, each ``"moe"`` or another kind;
+  the reference runs a layer of another kind with its ``block``;
+- ``build_params(seed, m)``: the program's parameter tree, with its groups,
+  made on the device in one jitted call;
+- ``layer_weights(key, m, kind)`` and ``outer_weights(key, m)``: layer
+  ``l``'s weights from ``layer_key(seed, l)`` (``l`` counts every layer of
+  the stack) and the embedding, final norm and head from
+  ``outer_key(seed)``, bit for bit as ``build_params`` places them;
+- ``moe_shape(m)``: (MoE layers, router width), which sizes the expert
+  cache, the prefetch predictor and the buddy tables;
+- ``token_flops(m, context)``: the operations of one token
+  (``harness/flops.py``).
+
+``m`` is the configuration file's ``"model"``. The weights and the buddy
+tables are the benchmark's own, drawn from the seed, so that the plain
+reference can draw the same ones again, one layer at a time, without taking
+anything from the program.
 """
 from __future__ import annotations
 
@@ -13,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness.flops import token_flops  # noqa: F401  (the builder's)
 from harness.traffic import seed_rng
 
 
@@ -30,8 +48,30 @@ def _dense(key, fan_in, fan_out, dtype, lead=()):
                    (2.0 / (fan_in + fan_out)) ** 0.5, dtype)
 
 
-def layer_weights(key, m: dict) -> dict:
-    """One attention + MoE block, in the program's layout (no layer axis)."""
+def model_config(cfg_file: dict):
+    """The ``ModelConfig`` that ``launch/serve.py`` would build."""
+    from repro.configs.base import ModelConfig, MoEConfig
+    m = cfg_file["model"]
+    return ModelConfig(
+        arch_id=cfg_file["name"], family="moe", source=cfg_file["source"],
+        num_layers=m["num_layers"], d_model=m["d_model"],
+        num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
+        d_ff=m["d_ff"], vocab_size=m["vocab_size"], head_dim=m["head_dim"],
+        moe=MoEConfig(**m["moe"]), sliding_window=m["sliding_window"],
+        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"], dtype=m["dtype"])
+
+
+def layer_kinds(m: dict) -> list:
+    return ["moe"] * m["num_layers"]
+
+
+def moe_shape(m: dict) -> tuple:
+    return m["num_layers"], m["moe"]["num_experts"]
+
+
+def layer_weights(key, m: dict, kind: str = "moe") -> dict:
+    """One attention + MoE block, in the program's layout (no layer axis);
+    every layer here is of kind ``"moe"``."""
     d, h, kv, hd = m["d_model"], m["num_heads"], m["num_kv_heads"], m["head_dim"]
     e, f, s = m["moe"]["num_experts"], m["moe"]["d_ff"], \
         m["moe"]["num_shared_experts"]
@@ -85,14 +125,14 @@ def build_params(seed: int, m: dict) -> dict:
     return jax.jit(make)(base_key(seed))
 
 
-def buddy_tables(seed: int, m: dict, list_len: int):
-    """[L, E, R] buddy lists: for every expert a ranked list of ``list_len``
-    other experts with strictly falling q, dealt from the seed. (Profiling
-    that makes them from router traces is the offline phase of the paper;
-    the reference needs tables that the program did not make.)"""
-    e = m["moe"]["num_experts"]
+def buddy_tables(seed: int, shape: tuple, list_len: int):
+    """[L, E, R] buddy lists for ``shape`` = (MoE layers L, experts E): for
+    every expert a ranked list of ``list_len`` other experts with strictly
+    falling q, dealt from the seed. (Profiling that makes them from router
+    traces is the offline phase of the paper; the reference needs tables
+    that the program did not make.)"""
+    l_n, e = shape
     rng = seed_rng(seed, 3)
-    l_n = m["num_layers"]
     table = np.full((l_n, e, list_len), -1, np.int32)
     q = np.zeros((l_n, e, list_len), np.float32)
     for l in range(l_n):

@@ -22,7 +22,7 @@ import time
 
 import numpy as np
 
-from harness.spec import Spec
+from harness.spec import Spec, load_builder
 from harness.traffic import make_items
 
 clock = time.perf_counter
@@ -70,32 +70,26 @@ def devices_for(chips: int, require_tpu: bool):
     return devs[:chips]
 
 
-def build_engine(cfg_file: dict, traffic: dict, seed: int):
+def build_engine(cfg_file: dict, traffic: dict, seed: int, builder):
     """The engine as ``launch/serve.py`` builds it (policy, cache, prefetch
-    predictor and budget), on the benchmark's weights and tables."""
-    from repro.configs.base import ModelConfig, MoEConfig
+    predictor and budget), on the weights and tables ``builder`` (the
+    configuration's, ``spec.load_builder``) makes from the seed."""
     from repro.core import BuddyPolicy
     from repro.core.buddies import BuddyTables
     from repro.runtime.cache import ExpertCache
     from repro.runtime.prefetch import PrevStepPredictor
 
-    from harness.model import buddy_tables, build_params
+    from harness.model import buddy_tables
     from harness.serving import BenchEngine
     m = cfg_file["model"]
-    cfg = ModelConfig(
-        arch_id=cfg_file["name"], family="moe", source=cfg_file["source"],
-        num_layers=m["num_layers"], d_model=m["d_model"],
-        num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
-        d_ff=m["d_ff"], vocab_size=m["vocab_size"], head_dim=m["head_dim"],
-        moe=MoEConfig(**m["moe"]), sliding_window=m["sliding_window"],
-        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"], dtype=m["dtype"])
-    params = build_params(seed, m)
+    cfg = builder.model_config(cfg_file)
+    params = builder.build_params(seed, m)
+    n_l, e = builder.moe_shape(m)
     r = cfg_file["buddy"]["list_len"]
-    table, q = buddy_tables(seed, m, r)
+    table, q = buddy_tables(seed, (n_l, e), r)
     tables = BuddyTables(table, q, np.full(table.shape[:2], r, np.int32))
     policy = BuddyPolicy(mode=traffic["policy"],
                          **cfg_file["buddy"]["policy"])
-    n_l, e = m["num_layers"], m["moe"]["num_experts"]
     cache = ExpertCache(n_l, e, traffic["cache_rate"])
     eng = BenchEngine(cfg, params, tables=tables, policy=policy, cache=cache,
                       predictor=PrevStepPredictor(n_l, e),
@@ -132,7 +126,9 @@ def warm_up(eng, slots: int, chunk: int, ctx: int):
     return cache_dtypes
 
 
-def step_flops(m: dict, steps: list, w0: float, w1: float) -> float:
+def step_flops(builder, m: dict, steps: list, w0: float, w1: float) -> float:
+    """Model operations of the steps that ended in the window, by the
+    builder's ``token_flops``."""
     from harness.flops import chunk_flops
     total = 0.0
     for st in steps:
@@ -140,7 +136,8 @@ def step_flops(m: dict, steps: list, w0: float, w1: float) -> float:
             continue
         for base, n in zip(st["pos"], st["counts"]):
             if n:
-                total += chunk_flops(m, int(base), int(n))
+                total += chunk_flops(m, int(base), int(n),
+                                     builder.token_flops)
     return total
 
 
@@ -185,8 +182,9 @@ def run_cell(root, name: str, seed: int, seconds: float, trace: bool, *,
     monitoring.register_event_duration_secs_listener(on_event)
 
     m = cfg_file["model"]
+    builder = load_builder(spec.root, cfg_file)
     slots, chunk = int(traffic["slots"]), int(traffic["prefill_chunk"])
-    eng, table = build_engine(cfg_file, traffic, seed)
+    eng, table = build_engine(cfg_file, traffic, seed, builder)
     eng.fault = fault
     if fault == "narrow_storage":        # the norm scales in bfloat16
         eng.params = jax.tree.map(
@@ -194,11 +192,11 @@ def run_cell(root, name: str, seed: int, seconds: float, trace: bool, *,
     rec = Recorder()
     eng.rec = rec
     rec.rows = lambda: [-1] * slots
-    items = make_items(traffic, seed, seconds, m["vocab_size"])
+    items = make_items(traffic, seed, seconds, eng.cfg.vocab_size)
     ctx = max(len(it.prompt) + it.max_new for it in items)
     in_use = [(devs[0].memory_stats() or {}).get("bytes_in_use", 0)]
     cache_dtypes = warm_up(eng, slots, chunk, ctx)
-    narrow = check.narrow_leaves([eng.params, cache_dtypes], m["dtype"])
+    narrow = check.narrow_leaves([eng.params, cache_dtypes], eng.cfg.dtype)
     in_use.append((devs[0].memory_stats() or {}).get("bytes_in_use", 0))
     print(f"set-up: {clock() - t_start:.3f} s, device bytes in use with the "
           f"weights {in_use[0]}, after warm-up {in_use[1]}", file=stdout)
@@ -249,7 +247,7 @@ def run_cell(root, name: str, seed: int, seconds: float, trace: bool, *,
     run = Run(requests=reqs, window_rids=window_rids,
               admitted=queue.admitted, emitted=sched.emitted, w0=w0, w1=w1,
               end=end, setup_s=w0 - t_start,
-              flops_window=step_flops(m, rec.steps, w0, w1),
+              flops_window=step_flops(builder, m, rec.steps, w0, w1),
               chips=len(devs))
     kind = devs[0].device_kind
     try:
@@ -292,7 +290,7 @@ def run_cell(root, name: str, seed: int, seconds: float, trace: bool, *,
         sample = []
     t_check = clock()
     compared = check.compare(cfg_file, spec.root, seed, sample, steps, table,
-                             control=control, pad_to=ctx)
+                             builder, control=control, pad_to=ctx)
     compared["narrow_leaves"] = narrow
     limits, at_least = cfg_file["limits"], traffic.get("at_least", {})
     print(f"check: {compared['requests']} requests, {compared['tokens']} "

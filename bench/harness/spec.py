@@ -5,11 +5,15 @@ own, and each metric in BENCHMARK.json is read by ``bench/metrics/<name>.py``
 or, where that file is missing, by the reader of its stem: the name up to
 its first ``.``, so that ``step_mfu.chat`` and ``step_mfu.batch`` share
 ``step_mfu.py`` and a later ``step_mfu.<kind>.py`` can read otherwise.
+A configuration file names its plain reference (``"reference"``) and may
+name the builder of its model (``"builder"``, see ``harness/model.py``), so
+that a new architecture too is new files.
 Adding a cell, a configuration, a traffic mix or a metric is therefore new
 files plus new entries in BENCHMARK.json: nothing here names any of them.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -75,3 +79,19 @@ def load_module(path: Path, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_builder(root, cfg_file: dict):
+    """The configuration's builder: the module its ``"builder"`` names
+    (relative to checkout ``root``), else ``harness.model``."""
+    if "builder" not in cfg_file:
+        from harness import model
+        return model
+    return _builder(str(Path(root) / cfg_file["builder"]))
+
+
+@functools.lru_cache(maxsize=None)
+def _builder(path: str):
+    """One module a file, so that the reference's compiled pieces, cached
+    by builder, are found again by the next run in the process."""
+    return load_module(Path(path), "builder_" + Path(path).stem)

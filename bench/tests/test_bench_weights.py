@@ -10,7 +10,7 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from harness.model import (buddy_tables, build_params, layer_key,  # noqa: E402
-                           layer_weights, outer_key, outer_weights)
+                           layer_weights, moe_shape, outer_key, outer_weights)
 from tiny import TINY_MODEL as M  # noqa: E402
 
 
@@ -34,7 +34,7 @@ def test_seeds_give_other_weights():
 
 
 def test_buddy_tables_are_ranked_lists_of_other_experts():
-    table, q = buddy_tables(5, M, 4)
+    table, q = buddy_tables(5, moe_shape(M), 4)
     l_n, e_n = M["num_layers"], M["moe"]["num_experts"]
     assert table.shape == (l_n, e_n, 4)
     for l in range(l_n):

@@ -1,7 +1,10 @@
-"""A checkout-shaped directory with one tiny cell, for tests on the CPU.
+"""A checkout-shaped directory with tiny cells, for tests on the CPU.
 
-It copies ``bench/`` and adds a configuration, a traffic mix and a cell
-as new files and entries only: the same way a later cell is added."""
+It copies ``bench/`` and adds configurations, a traffic mix and cells as
+new files and entries only: the same way a later cell is added. The cell
+``tiny-built-chat`` runs the same model through a configuration that names
+a builder file of its own (a copy of the default, ``harness/model.py``),
+as a new architecture is added."""
 import json
 import shutil
 from pathlib import Path
@@ -15,6 +18,9 @@ TINY_MODEL = {
     "norm_eps": 1e-5, "sliding_window": 0, "dtype": "float32",
     "moe": {"num_experts": 8, "top_k": 2, "d_ff": 32,
             "num_shared_experts": 1}}
+
+BUILDER = "bench/tests/tiny_builder.py"
+CELL_BUILT = "tiny-built-chat"
 
 TINY_TRAFFIC = {
     "why": "tiny open-loop mix for tests on the CPU",
@@ -35,6 +41,8 @@ def make_root(tmp: Path, limits=None, traffic=None) -> Path:
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (root / "bench/tests").mkdir()
+    shutil.copy(BENCH / "harness/model.py", root / BUILDER)
     cfg = {"name": "tiny", "source": "bench/tests/tiny.py",
            "model": TINY_MODEL,
            "buddy": {"list_len": 4,
@@ -43,16 +51,19 @@ def make_root(tmp: Path, limits=None, traffic=None) -> Path:
            "limits": limits or {"token_gap": 1e-3, "route_gap": 1e-3,
                                 "decision_errors": 0, "narrow_leaves": 0}}
     (root / "bench/configs/tiny.json").write_text(json.dumps(cfg))
+    (root / "bench/configs/tiny-built.json").write_text(
+        json.dumps(dict(cfg, name="tiny-built", builder=BUILDER)))
     (root / "bench/traffic/tiny-chat.json").write_text(
         json.dumps(traffic or TINY_TRAFFIC))
-    spec["configs"].append({"name": "tiny", "source": "bench/tests/tiny.py",
-                            "file": "bench/configs/tiny.json",
-                            "reduced": [], "why": "tests"})
-    spec["workloads"].append({"name": "tiny-chat", "config": "tiny",
-                              "traffic": "tiny-chat", "chips": 1,
-                              "why": "tests"})
+    for name, cell in (("tiny", "tiny-chat"), ("tiny-built", CELL_BUILT)):
+        spec["configs"].append({"name": name, "source": "bench/tests/tiny.py",
+                                "file": f"bench/configs/{name}.json",
+                                "reduced": [], "why": "tests"})
+        spec["workloads"].append({"name": cell, "config": name,
+                                  "traffic": "tiny-chat", "chips": 1,
+                                  "why": "tests"})
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m and "dsv2lite-chat-miss50" in m["workloads"]:
-            m["workloads"].append("tiny-chat")
+            m["workloads"] += ["tiny-chat", CELL_BUILT]
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return root

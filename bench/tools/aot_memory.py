@@ -26,9 +26,7 @@ def main():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from harness.model import build_params
-    from harness.spec import Spec
-    from repro.configs.base import ModelConfig, MoEConfig
+    from harness.spec import Spec, load_builder
     from repro.core import BuddyPolicy
     from repro.models import transformer
     from repro.models.moe import BuddyState
@@ -38,13 +36,8 @@ def main():
     cell = spec.workload(args.workload)
     cf, tf = spec.config(cell), spec.traffic(cell)
     m = cf["model"]
-    cfg = ModelConfig(
-        arch_id=cf["name"], family="moe", source="", num_layers=m["num_layers"],
-        d_model=m["d_model"], num_heads=m["num_heads"],
-        num_kv_heads=m["num_kv_heads"], d_ff=m["d_ff"],
-        vocab_size=m["vocab_size"], head_dim=m["head_dim"],
-        moe=MoEConfig(**m["moe"]), sliding_window=m["sliding_window"],
-        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"], dtype=m["dtype"])
+    builder = load_builder(spec.root, cf)
+    cfg = builder.model_config(cf)
     b = args.slots or tf["slots"]
     c = args.chunk or tf["prefill_chunk"]
     ctx = args.ctx or (tf["prompt_len"]["max"] + tf["output_len"]["max"])
@@ -60,10 +53,10 @@ def main():
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
-    params = place(jax.eval_shape(lambda: build_params(0, m)))
+    params = place(jax.eval_shape(lambda: builder.build_params(0, m)))
     caches = place(jax.eval_shape(
         lambda: transformer.init_caches(cfg, b, ctx)))
-    n_l, e = m["num_layers"], m["moe"]["num_experts"]
+    n_l, e = builder.moe_shape(m)
     r = cf["buddy"]["list_len"]
     buddies = BuddyState(resident=sds((n_l, e), jnp.bool_),
                          table=sds((n_l, e, r), jnp.int32),
